@@ -10,7 +10,7 @@ permuted block tables, sliding windows, zero-size expert groups).
 On CPU the pallas side runs through the Pallas interpreter, so the
 wall-clock columns describe the interpreter, not production kernels —
 the parity columns are the point there (CI runs this to pin the
-kernel-backend contract); on TPU/GPU the timings compare compiled Pallas
+kernel-backend contract); on TPU the timings compare compiled Pallas
 against XLA.  Emits one JSON row per case::
 
   PYTHONPATH=src python -m benchmarks.kernel_hotpath --out BENCH_kernels.json
@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import flash_attention, moe_gmm, paged_attention
-from repro.kernels.ops import _default_interpret
+from repro.kernels.ops import _interpret
 from repro.kernels.ref import (flash_attention_ref, moe_gmm_ref,
                                paged_attention_ref)
 
@@ -135,7 +135,7 @@ def run(reps: int = 5, seed: int = 0):
 
     return {
         "jax_backend": jax.default_backend(),
-        "pallas_interpret": _default_interpret(),
+        "pallas_interpret": _interpret(),
         "reps": reps,
         "cases": rows,
         "all_parity": all(r["parity"] for r in rows),

@@ -82,9 +82,10 @@ class ArchConfig:
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"
     # kernel backend for the serving hot path: "reference" (pure-JAX
-    # twins), "pallas" (flash prefill / paged decode / MoE GMM), or
-    # "auto" (pallas on TPU/GPU, interpret-mode pallas for CPU
-    # validation, reference otherwise) — see repro.kernels.resolve_backend
+    # twins) or "pallas"/"auto" (flash prefill / paged decode / MoE GMM:
+    # compiled on TPU, interpreted on CPU).  No silent fallback: another
+    # platform, or a config the kernels cannot serve (non-attention
+    # stages, tp > 1), raises — see repro.kernels.resolve_backend
     kernels: str = "reference"
 
     @property

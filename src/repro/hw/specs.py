@@ -24,6 +24,27 @@ _REGISTRY = {
 }
 
 
+#: jax ``device_kind`` of an accelerator -> its spec in the registry
+_DEVICE_KINDS = {
+    "TPU v5 lite": "tpu-v5e",
+    "TPU v6 lite": "tpu-v6e",
+}
+
+
+def hw_for_device(device) -> HardwareSpec:
+    """Spec of a live jax device, keyed by its ``device_kind``.  The CPU
+    maps to ``cpu-engine``; an accelerator kind the table does not know
+    is an error, never a default."""
+    if device.platform == "cpu":
+        return ENGINE_HW
+    name = _DEVICE_KINDS.get(device.device_kind)
+    if name is None:
+        raise KeyError(f"no hardware spec for device_kind "
+                       f"{device.device_kind!r} ({device.platform}); "
+                       f"known: {sorted(_DEVICE_KINDS)}")
+    return _REGISTRY[name]
+
+
 def get_hw(name: str) -> HardwareSpec:
     if name not in _REGISTRY:
         raise KeyError(f"unknown hardware {name!r}; have {sorted(_REGISTRY)}")

@@ -1,10 +1,12 @@
 """Pallas TPU grouped expert matmul (MegaBlocks-style, dense-padded groups).
 
 Computes out[e] = x[e] @ w[e] for E experts with per-expert valid row counts
-(``group_sizes``): rows past a group's size produce zeros and — on real
-TPU — their tiles are skipped via @pl.when (compute proportional to actual
-load, which is what makes top-k MoE cheap). Grid (E, nC): one (expert,
-row-block) tile per program; d and f stay resident in VMEM per expert.
+(``group_sizes``, scalar-prefetched into SMEM): rows past a group's size
+produce zeros and their tiles are skipped via @pl.when (compute
+proportional to actual load, which is what makes top-k MoE cheap).  Grid
+(E, nC): one (expert, row-block) tile per program; d and f stay resident
+in VMEM per expert.  The capacity axis is zero-padded to a whole number
+of row blocks so every block meets the TPU's tiling rule.
 """
 from __future__ import annotations
 
@@ -13,13 +15,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _gmm_kernel(x_ref, w_ref, gs_ref, o_ref, *, bc: int):
-    # x_ref: (1, bc, d); w_ref: (1, d, f); gs_ref: (1,); o_ref: (1, bc, f)
-    ci = pl.program_id(1)
-    size = gs_ref[0]
-    start = ci * bc
+def _gmm_kernel(gs_ref, x_ref, w_ref, o_ref, *, bc: int):
+    # x_ref: (1, bc, d); w_ref: (1, d, f); o_ref: (1, bc, f)
+    size = gs_ref[pl.program_id(0)]
+    start = pl.program_id(1) * bc
 
     @pl.when(start < size)
     def _():
@@ -37,26 +39,28 @@ def _gmm_kernel(x_ref, w_ref, gs_ref, o_ref, *, bc: int):
 
 
 def moe_gmm_pallas(x, w, group_sizes, *, bc: int = 128,
-                   interpret: bool = True):
+                   interpret: bool = False):
     """x: (E,C,d); w: (E,d,f); group_sizes: (E,) -> (E,C,f)."""
     E, C, d = x.shape
     f = w.shape[-1]
-    bc = min(bc, C)
-    if C % bc:
-        # expert capacity is workload-derived and rarely a multiple of the
-        # tile size; shrink to the largest divisor rather than rejecting
-        bc = next(b for b in range(bc, 0, -1) if C % b == 0)
-    grid = (E, C // bc)
-    kernel = functools.partial(_gmm_kernel, bc=bc)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bc, d), lambda e, c: (e, c, 0)),
-            pl.BlockSpec((1, d, f), lambda e, c: (e, 0, 0)),
-            pl.BlockSpec((1,), lambda e, c: (e,)),
-        ],
-        out_specs=pl.BlockSpec((1, bc, f), lambda e, c: (e, c, 0)),
-        out_shape=jax.ShapeDtypeStruct((E, C, f), x.dtype),
+    # expert capacity is workload-derived and rarely a multiple of the
+    # tile: use one block of C rounded up to the sublane multiple when it
+    # is small, otherwise pad C to a whole number of bc-row blocks
+    bc = min(bc, -(-C // 8) * 8)
+    Cp = -(-C // bc) * bc
+    if Cp != C:
+        x = jnp.pad(x, ((0, 0), (0, Cp - C), (0, 0)))
+    out = pl.pallas_call(
+        functools.partial(_gmm_kernel, bc=bc),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(E, Cp // bc),
+            in_specs=[pl.BlockSpec((1, bc, d), lambda e, c, gs: (e, c, 0)),
+                      pl.BlockSpec((1, d, f), lambda e, c, gs: (e, 0, 0))],
+            out_specs=pl.BlockSpec((1, bc, f), lambda e, c, gs: (e, c, 0))),
+        out_shape=jax.ShapeDtypeStruct((E, Cp, f), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(x, w, group_sizes)
+    )(group_sizes.astype(jnp.int32), x, w)
+    return out[:, :C]

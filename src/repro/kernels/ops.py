@@ -1,22 +1,20 @@
 """jit'd public wrappers for the Pallas kernels + kernel-backend selection.
 
-``interpret`` defaults to True on CPU hosts (semantics validation through
-the Pallas interpreter) and False on real accelerators (TPU *and* GPU —
-compiled Pallas; keying on TPU alone would silently run a GPU in the
-interpreter).  ``REPRO_PALLAS_INTERPRET=0|1`` overrides either way, and
-every wrapper takes an explicit ``interpret=`` for per-call control.
+The platform decides how a kernel runs: compiled on a TPU, through the
+Pallas interpreter on a CPU host (semantics validation in tests).  Any
+other platform is an error, and so is an explicit ``interpret=`` that
+contradicts the platform — a TPU never silently runs the interpreter.
 
 ``resolve_backend`` maps the engine-facing choice (``"reference" |
 "pallas" | "auto"``) to a concrete ``(backend, interpret)`` pair:
-``auto`` is compiled Pallas on TPU/GPU, interpret-mode Pallas on CPU
-(validation), and the pure-JAX reference anywhere else.
+``"reference"`` is the pure-JAX path anywhere; ``"pallas"`` and ``"auto"``
+are the Pallas kernels, interpreted on CPU and compiled on TPU.
 
 Interfaces mirror the pure-JAX twins in repro.models.
 """
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -26,43 +24,39 @@ from repro.kernels.moe_gmm import moe_gmm_pallas
 from repro.kernels.paged_attention import paged_attention_pallas
 
 KERNEL_BACKENDS = ("reference", "pallas", "auto")
+#: platform -> whether Pallas kernels run in the interpreter there
+_INTERPRET_ON = {"cpu": True, "tpu": False}
 
 
-def _env_interpret() -> Optional[bool]:
-    """REPRO_PALLAS_INTERPRET escape hatch: force interpret on/off."""
-    v = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if v is None:
-        return None
-    return v.strip().lower() not in ("0", "false", "no", "off")
-
-
-def _default_interpret() -> bool:
-    env = _env_interpret()
-    if env is not None:
-        return env
-    # compiled Pallas on real accelerators (TPU and GPU); the interpreter
-    # everywhere else.  A bare `!= "tpu"` here would leave a CUDA backend
-    # silently interpreting every kernel.
-    return jax.default_backend() not in ("tpu", "gpu")
+def _interpret(interpret: Optional[bool] = None) -> bool:
+    """The interpret flag the current platform requires; an explicit
+    ``interpret`` that disagrees is an error, not an override."""
+    platform = jax.default_backend()
+    if platform not in _INTERPRET_ON:
+        raise RuntimeError(
+            f"Pallas kernels run compiled on TPU or interpreted on CPU; "
+            f"platform {platform!r} has neither")
+    want = _INTERPRET_ON[platform]
+    if interpret is not None and interpret != want:
+        raise ValueError(f"interpret={interpret} on platform {platform!r}: "
+                         f"Pallas kernels run "
+                         f"{'interpreted' if want else 'compiled'} there")
+    return want
 
 
 def resolve_backend(choice: str) -> Tuple[str, bool]:
     """Engine kernel choice -> (backend, interpret).
 
-    "reference"  pure-JAX twins (layers.decode_attention & co).
-    "pallas"     Pallas kernels, interpret resolved by platform/env.
-    "auto"       pallas compiled on TPU/GPU, pallas interpreted on CPU
-                 (so CI validates the production path), reference on
-                 anything unrecognized.
+    "reference"        pure-JAX twins (layers.decode_attention & co).
+    "pallas" / "auto"  Pallas kernels: compiled on TPU, interpreted on
+                       CPU; any other platform raises.
     """
     if choice not in KERNEL_BACKENDS:
         raise ValueError(
             f"kernels={choice!r}: expected one of {KERNEL_BACKENDS}")
     if choice == "reference":
         return "reference", False
-    if choice == "pallas" or jax.default_backend() in ("tpu", "gpu", "cpu"):
-        return "pallas", _default_interpret()
-    return "reference", False
+    return "pallas", _interpret()
 
 
 @functools.partial(jax.jit,
@@ -79,11 +73,9 @@ def flash_attention(q, k, v, lengths=None, window=None, *, bq: int = 128,
     if window is not None and not causal:
         raise ValueError("flash_attention: window requires causal=True "
                          "(sliding windows are causal by definition)")
-    if interpret is None:
-        interpret = _default_interpret()
     return flash_attention_pallas(q, k, v, lengths=lengths, window=window,
                                   bq=bq, bkv=bkv, causal=causal,
-                                  interpret=interpret)
+                                  interpret=_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
@@ -94,16 +86,14 @@ def paged_attention(q, k_pages, v_pages, block_table, lengths, *,
     Extend: q (B,S,H,dh) with ``start`` (B,), queries at start..start+S-1.
     k_pages/v_pages: (P,ps,KV,dh); block_table: (B,maxp) int32;
     ``window`` as in flash_attention."""
-    if interpret is None:
-        interpret = _default_interpret()
     return paged_attention_pallas(q, k_pages, v_pages, block_table, lengths,
                                   page_size=page_size, start=start,
-                                  window=window, interpret=interpret)
+                                  window=window,
+                                  interpret=_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("bc", "interpret"))
 def moe_gmm(x, w, group_sizes, *, bc: int = 128,
             interpret: Optional[bool] = None):
-    if interpret is None:
-        interpret = _default_interpret()
-    return moe_gmm_pallas(x, w, group_sizes, bc=bc, interpret=interpret)
+    return moe_gmm_pallas(x, w, group_sizes, bc=bc,
+                          interpret=_interpret(interpret))

@@ -1,10 +1,18 @@
 """Pallas TPU paged attention (block-table indirection, decode + extend).
 
 The serving engine's KV lives in fixed-size pages (PagedAttention [9]); a
-per-sequence block table maps logical positions to pages.  Grid (B, KV):
-each program owns one (sequence, kv-head) pair, walking its block table
-with online softmax.  Page loads are dynamic gathers (on real TPU these are
-HBM->VMEM DMAs; ``interpret=True`` validates semantics on CPU).
+per-sequence block table maps logical positions to pages.  Grid
+(B, row-blocks, pages): each program takes one page of one sequence,
+fetched by DMA through the scalar-prefetched block table, and folds it
+into the online-softmax state of every KV head (VMEM scratch carried
+across the page axis).  A page block is ``(1, ps, KV, dh)``, whose
+trailing dims are the pool's own, so it meets the TPU's tiling rule at
+any width.  Pages past a sequence's length are predicated off and their
+index map repeats the last live page, so no DMA is issued for them.
+
+Queries are laid out head-major, ``(B, KV, S*G, dh)``: row ``r`` of a
+KV head's block is query position ``start + r // G``.  Long extend
+chunks are split into row blocks to bound VMEM.
 
 One kernel serves both serving phases:
 
@@ -24,14 +32,23 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 NO_WINDOW = 1 << 30
+#: most query rows (S*G) one program holds per KV head
+MAX_ROWS = 512
+
+
+def _row_block(R: int) -> int:
+    if R <= MAX_ROWS:
+        return R
+    return next((b for b in range(MAX_ROWS, 7, -8) if R % b == 0), R)
 
 
 def paged_attention_pallas(q, k_pages, v_pages, block_table, lengths, *,
                            page_size: int, start=None, window=None,
-                           interpret: bool = True):
+                           interpret: bool = False):
     """q: (B,H,dh) decode or (B,S,H,dh) extend; k_pages/v_pages:
     (P,ps,KV,dh); block_table: (B,maxp) int32; lengths: (B,).
     ``start``: (B,) first query position (extend; decode infers
@@ -41,8 +58,11 @@ def paged_attention_pallas(q, k_pages, v_pages, block_table, lengths, *,
         q = q[:, None]          # (B, 1, H, dh)
     B, S, H, dh = q.shape
     P, ps, KV, _ = k_pages.shape
-    assert ps == page_size
+    if ps != page_size:
+        raise ValueError(f"page pool has pages of {ps}, not {page_size}")
     G = H // KV
+    R = S * G
+    br = _row_block(R)
     maxp = block_table.shape[1]
     lengths = lengths.astype(jnp.int32)
     if start is None:
@@ -55,75 +75,82 @@ def paged_attention_pallas(q, k_pages, v_pages, block_table, lengths, *,
     if window is None:
         window = NO_WINDOW
     win = jnp.reshape(jnp.asarray(window, jnp.int32), (1,))
-    qr = q.reshape(B, S, KV, G, dh)
-    grid = (B, KV)
-    kernel = functools.partial(_paged_kernel, page_size=page_size)
+    qr = q.reshape(B, S, KV, G, dh).transpose(0, 2, 1, 3, 4) \
+        .reshape(B, KV, R, dh)
+
+    def q_map(b, r, j, table, start_ref, len_ref, win_ref):
+        return b, 0, r, 0
+
+    def page_map(b, r, j, table, start_ref, len_ref, win_ref):
+        n_used = jnp.clip((len_ref[b] + ps - 1) // ps, 1, maxp)
+        return table[b * maxp + jnp.minimum(j, n_used - 1)], 0, 0, 0
+
+    kernel = functools.partial(_paged_kernel, page_size=ps, G=G, br=br)
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, S, 1, G, dh), lambda b, kv: (b, 0, kv, 0, 0)),
-            pl.BlockSpec((P, ps, 1, dh), lambda b, kv: (0, 0, kv, 0)),
-            pl.BlockSpec((P, ps, 1, dh), lambda b, kv: (0, 0, kv, 0)),
-            pl.BlockSpec((1, maxp), lambda b, kv: (b, 0)),
-            pl.BlockSpec((1,), lambda b, kv: (b,)),
-            pl.BlockSpec((1,), lambda b, kv: (b,)),
-            pl.BlockSpec((1,), lambda b, kv: (0,)),
-        ],
-        out_specs=pl.BlockSpec((1, S, 1, G, dh),
-                               lambda b, kv: (b, 0, kv, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, S, KV, G, dh), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B, R // br, maxp),
+            in_specs=[pl.BlockSpec((1, KV, br, dh), q_map),
+                      pl.BlockSpec((1, ps, KV, dh), page_map),
+                      pl.BlockSpec((1, ps, KV, dh), page_map)],
+            out_specs=pl.BlockSpec((1, KV, br, dh), q_map),
+            scratch_shapes=[pltpu.VMEM((KV, br, 1), jnp.float32),
+                            pltpu.VMEM((KV, br, 1), jnp.float32),
+                            pltpu.VMEM((KV, br, dh), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, KV, R, dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(qr, k_pages, v_pages, block_table, start, lengths, win)
-    out = out.reshape(B, S, H, dh)
+    )(block_table.reshape(-1).astype(jnp.int32), start, lengths, win,
+      qr, k_pages, v_pages)
+    out = out.reshape(B, KV, S, G, dh).transpose(0, 2, 1, 3, 4) \
+        .reshape(B, S, H, dh)
     return out[:, 0] if squeeze else out
 
 
-def _paged_kernel(q_ref, kp_ref, vp_ref, table_ref, start_ref, len_ref,
-                  win_ref, o_ref, *, page_size: int):
-    """One (sequence, kv-head): S*G query rows x this sequence's pages."""
-    S, G, dh = q_ref.shape[1], q_ref.shape[3], q_ref.shape[4]
-    R = S * G
-    q = q_ref[0, :, 0, :, :].astype(jnp.float32).reshape(R, dh) * dh ** -0.5
-    start = start_ref[0]
-    length = len_ref[0]
-    window = win_ref[0]
-    # cap at the table's reach: an unscheduled-but-full slot arrives with
-    # length == capacity + 1 and must not walk past the last table entry
-    n_used = jnp.minimum((length + page_size - 1) // page_size,
-                         table_ref.shape[1])
-    # row r of the flattened (S*G) query block sits at position start + r//G
-    q_pos = start + jax.lax.broadcasted_iota(jnp.int32, (R, page_size),
-                                             0) // G
+def _paged_kernel(table_ref, start_ref, len_ref, win_ref, q_ref, k_ref,
+                  v_ref, o_ref, m_sc, l_sc, acc_sc, *, page_size: int,
+                  G: int, br: int):
+    """One (sequence, row block, page): every KV head's rows x one page."""
+    del table_ref   # consumed by the index maps
+    b, r, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    KV, dh = q_ref.shape[1], q_ref.shape[3]
+    length = len_ref[b]
 
-    def body(j, carry):
-        acc, m, l = carry
-        page = table_ref[0, j]
-        # unit dslice for the kv-head dim: raw ints in pl.load index tuples
-        # crash this jax version's interpret-mode discharge
-        k = pl.load(kp_ref, (page, slice(None), pl.dslice(0, 1),
-                             slice(None)))[:, 0, :].astype(jnp.float32)
-        v = pl.load(vp_ref, (page, slice(None), pl.dslice(0, 1),
-                             slice(None)))[:, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+    @pl.when(j == 0)
+    def _init():
+        m_sc[...] = jnp.full(m_sc.shape, NEG_INF, jnp.float32)
+        l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+        acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+
+    @pl.when(j * page_size < length)
+    def _step():
+        rows = r * br + jax.lax.broadcasted_iota(jnp.int32, (br, page_size),
+                                                 0)
+        q_pos = start_ref[b] + rows // G
         kv_pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (R, page_size), 1)
+            jnp.int32, (br, page_size), 1)
         mask = (kv_pos <= q_pos) & (kv_pos < length) \
-            & (q_pos - kv_pos < window)
-        s = jnp.where(mask, s, NEG_INF)
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m - m_new)
-        l_new = l * corr + p.sum(axis=-1)
-        acc = acc * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return acc, m_new, l_new
+            & (q_pos - kv_pos < win_ref[0])
+        for h in range(KV):
+            q = q_ref[0, h].astype(jnp.float32) * dh ** -0.5
+            k = k_ref[0, :, h, :].astype(jnp.float32)
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(mask, s, NEG_INF)
+            m_prev = m_sc[h]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_sc[h] = l_sc[h] * corr + p.sum(axis=-1, keepdims=True)
+            acc_sc[h] = acc_sc[h] * corr + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_sc[h] = m_new
 
-    acc0 = jnp.zeros((R, dh), jnp.float32)
-    m0 = jnp.full((R,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((R,), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(0, n_used, body, (acc0, m0, l0))
-    o_ref[0, :, 0, :, :] = (acc / jnp.maximum(l, 1e-20)[:, None]
-                            ).reshape(S, G, dh).astype(o_ref.dtype)
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finish():
+        o_ref[0] = (acc_sc[...] / jnp.maximum(l_sc[...], 1e-20)
+                    ).astype(o_ref.dtype)

@@ -6,6 +6,15 @@ touches jax device state; the dry-run sets XLA_FLAGS before first jax init.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with Auto axes: GSPMD propagates shardings through
+    gathers and jits from their committed inputs (explicit axes, the
+    default, would demand ``out_sharding=`` on every such op)."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,14 +23,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     import numpy as np
     n = int(np.prod(shape))
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return _mesh(shape, axes, devices=jax.devices()[:n])
 
 
 def make_host_mesh(model_parallel: int = 1):
     """Degenerate mesh over the actually-available devices (smoke tests)."""
     n = len(jax.devices())
-    return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"))
+    return _mesh((n // model_parallel, model_parallel), ("data", "model"))
 
 
 def make_engine_mesh(tp: int = 1):
@@ -39,7 +47,7 @@ def make_engine_mesh(tp: int = 1):
             f"{len(devs)} are visible; on CPU set "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={tp} "
             f"before importing jax")
-    return jax.make_mesh((1, tp), ("data", "model"), devices=devs[:tp])
+    return _mesh((1, tp), ("data", "model"), devices=devs[:tp])
 
 
 def dp_axes(mesh) -> tuple:

@@ -8,9 +8,47 @@ from __future__ import annotations
 import argparse
 import json
 
-from repro.configs import get_config
+from repro.configs import ArchConfig, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import DriverCfg, ServeDriver, ServingEngine
 from repro.workload import ShareGPTConfig, generate
+
+
+def build_driver(cfg: ArchConfig, *, params=None, instances: int = 1,
+                 pd: bool = False, prefix_cache: bool = False,
+                 max_batch: int = 4, max_len: int = 512, tp: int = 1,
+                 router: str = "round_robin",
+                 chunked_prefill: bool = False) -> ServeDriver:
+    """Engines + scheduler + ``ServeDriver`` for one served model.
+
+    Every engine shares the first engine's params (or ``params``).  With
+    ``pd`` one prefill engine hands off to one decode engine; otherwise
+    ``instances`` unified engines sit behind ``router``.
+    ``chunked_prefill`` gives the runtime continuous batching with
+    64-token prefill chunks (later chunks run the engine's extend path).
+    """
+    kw = dict(max_batch=max_batch, max_len=max_len,
+              prefix_cache=prefix_cache, tp=tp)
+    if pd:
+        p0 = ServingEngine(cfg, params=params, name="p0", role="prefill",
+                           **kw)
+        engines = [p0, ServingEngine(cfg, params=p0.params, name="d0",
+                                     role="decode", **kw)]
+        pd_map = {"p0": ("d0",)}
+    else:
+        e0 = ServingEngine(cfg, params=params, name="e0", **kw)
+        engines = [e0] + [
+            ServingEngine(cfg, params=e0.params, name=f"e{i}", **kw)
+            for i in range(1, instances)]
+        pd_map = None
+    sched = None
+    if chunked_prefill:
+        from repro.core.config import SchedulerCfg
+        sched = SchedulerCfg(max_batch_size=max_batch,
+                             max_batch_tokens=256,
+                             chunked_prefill=True, prefill_chunk=64)
+    return ServeDriver(engines, DriverCfg(router=router, scheduler=sched),
+                       pd_map=pd_map)
 
 
 def main():
@@ -35,32 +73,17 @@ def main():
                          "real engine (unified runtime scheduler)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     reqs = generate(ShareGPTConfig(
         n_requests=args.n, rate=args.rate, vocab=cfg.vocab,
         mean_prompt=90, mean_output=24, max_prompt=args.max_len // 2,
         max_output=48, share_fraction=0.5 if args.prefix_cache else 0.0))
-    kw = dict(max_batch=args.max_batch, max_len=args.max_len,
-              prefix_cache=args.prefix_cache, tp=args.tp)
-    if args.pd:
-        p0 = ServingEngine(cfg, name="p0", role="prefill", **kw)
-        engines = [p0, ServingEngine(cfg, params=p0.params, name="d0",
-                                     role="decode", **kw)]
-        pd = {"p0": ("d0",)}
-    else:
-        e0 = ServingEngine(cfg, name="e0", **kw)
-        engines = [e0] + [
-            ServingEngine(cfg, params=e0.params, name=f"e{i}", **kw)
-            for i in range(1, args.instances)]
-        pd = None
-    sched = None
-    if args.chunked_prefill:
-        from repro.core.config import SchedulerCfg
-        sched = SchedulerCfg(max_batch_size=args.max_batch,
-                             max_batch_tokens=256,
-                             chunked_prefill=True, prefill_chunk=64)
-    drv = ServeDriver(engines, DriverCfg(router=args.router,
-                                         scheduler=sched), pd_map=pd)
+    drv = build_driver(cfg, instances=args.instances, pd=args.pd,
+                       prefix_cache=args.prefix_cache,
+                       max_batch=args.max_batch, max_len=args.max_len,
+                       tp=args.tp, router=args.router,
+                       chunked_prefill=args.chunked_prefill)
     m = drv.run(reqs)
     print(json.dumps(m, indent=1, default=float))
 

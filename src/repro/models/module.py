@@ -1,7 +1,7 @@
 """Tiny pytree-parameter module substrate (flax is not installed).
 
 Params are nested dicts of jnp arrays. Initializers take an explicit PRNG
-key; stacked (scanned) stages are initialized with vmap over a key batch so
+key; stacked (scanned) stages are initialized by mapping over a key batch so
 every layer gets independent weights while the HLO stays a single scan body.
 """
 from __future__ import annotations
@@ -33,9 +33,11 @@ def ones(shape, dtype=jnp.float32):
 
 
 def stack_init(key, n: int, init_fn):
-    """vmap an init function over ``n`` independent keys -> stacked params."""
+    """Map an init function over ``n`` independent keys -> stacked params,
+    one element at a time (under jit only one element's intermediates are
+    live)."""
     keys = jax.random.split(key, n)
-    return jax.vmap(init_fn)(keys)
+    return jax.lax.map(init_fn, keys)
 
 
 def param_count(tree) -> int:

@@ -421,8 +421,16 @@ class Model:
                          page_size=self.page_size)
 
     # ---- init ----
-    def init(self, key) -> dict:
+    def init(self, key, out_shardings=None) -> dict:
+        """Random weights in ``cfg.param_dtype``.  One jit builds them,
+        casting each layer as it is made, so no float32 copy of a stage
+        is ever materialised (a full-width stage would not fit a chip);
+        ``out_shardings`` builds them straight into a sharded layout."""
+        return jax.jit(self._init, out_shardings=out_shardings)(key)
+
+    def _init(self, key) -> dict:
         cfg = self.cfg
+        dtype = jnp.dtype(cfg.param_dtype)
         keys = jax.random.split(key, len(cfg.stages) + 4)
         params: Dict[str, Any] = {}
         if cfg.embed_inputs:
@@ -430,20 +438,18 @@ class Model:
                                                    cfg.d_model)}
         for i, st in enumerate(cfg.stages):
             init_fn = _STAGE_INIT[st.kind]
-            if st.kind in (ATTN_MLP, ATTN_MOE):
-                params[f"stage{i}"] = m.stack_init(
-                    keys[i + 1], st.n_layers,
-                    lambda k: init_fn(k, cfg, self.fuse_qkv))
-            else:
-                params[f"stage{i}"] = m.stack_init(
-                    keys[i + 1], st.n_layers, lambda k: init_fn(k, cfg))
+            args = (self.fuse_qkv,) if st.kind in (ATTN_MLP, ATTN_MOE) \
+                else ()
+            params[f"stage{i}"] = m.stack_init(
+                keys[i + 1], st.n_layers,
+                lambda k: m.cast_tree(init_fn(k, cfg, *args), dtype))
         if any(st.kind == ZAMBA_SUPER for st in cfg.stages):
             params["shared_attn"] = _init_attn_mlp_layer(keys[-3], cfg)
         params["final_norm"] = m.zeros((cfg.d_model,))
         nout = max(1, cfg.n_codebooks or 1)
         params["head"] = {"w": m.dense_init(keys[-2], cfg.d_model,
                                             nout * cfg.padded_vocab)}
-        return params
+        return m.cast_tree(params, dtype)
 
     # ---- embedding / head ----
     def _embed(self, params, tokens):

@@ -187,10 +187,12 @@ class JaxBackend:
                 pad = jnp.zeros((1, P), jnp.int32)
                 try:
                     sub = eng._slot_subcache(0, 16)
-                    jax.block_until_ready(eng._jit_extend(
-                        eng.params, sub, pad, jnp.asarray([P], jnp.int32)))
-                    # the chunk write-back (slot update) compiles once
+                    _, sub = eng._jit_extend(eng.params, sub, pad,
+                                             jnp.asarray([P], jnp.int32))
+                    # the chunk write-back (slot update) compiles once;
+                    # the extend donated ``sub``, so adopt its output
                     eng._write_slot(0, sub, 16)
+                    jax.block_until_ready(eng.cache)
                 except NotImplementedError:
                     break   # no cached-prefill path (e.g. xLSTM)
                 P *= 2

@@ -18,10 +18,13 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from repro.core.config import (ENGINE_HW, ClusterCfg, InstanceCfg,
-                               NetworkCfg, ParallelismCfg, PrefixCacheCfg,
-                               RouterCfg, SchedulerCfg, engine_scheduler_cfg)
+import jax
+
+from repro.core.config import (ClusterCfg, InstanceCfg, NetworkCfg,
+                               ParallelismCfg, PrefixCacheCfg, RouterCfg,
+                               SchedulerCfg, engine_scheduler_cfg)
 from repro.core.request import SimRequest
+from repro.hw.specs import hw_for_device
 from repro.runtime.backends.jax_engine import JaxBackend
 from repro.runtime.cluster import ServingRuntime
 from repro.serve.engine import ServingEngine
@@ -43,8 +46,10 @@ def engine_instance_cfg(engine: ServingEngine,
     comparable ``expert_load`` / ``spec_decode`` metrics.  A speculating
     engine always mirrors its draft length into the scheduler
     (``decode_tokens = k + 1``) so the KV ledger reserves the real
-    verification window.  ``hw`` overrides the default ``ENGINE_HW``
-    spec and ``prefix_cache`` the derived ``PrefixCacheCfg`` — e.g. a
+    verification window.  ``hw`` defaults to the spec of the device the
+    engine's params live on (``repro.hw.specs.hw_for_device``), so the KV
+    ledger is sized for that device; ``hw`` overrides it and
+    ``prefix_cache`` the derived ``PrefixCacheCfg`` — e.g. a
     sim-vs-real KV-tier comparison shrinking tier capacities so both
     backends walk the same spill chain (``tests/test_kv_tiers.py``).
     """
@@ -63,13 +68,16 @@ def engine_instance_cfg(engine: ServingEngine,
     if engine.spec is not None:
         scheduler = dataclasses.replace(scheduler,
                                         decode_tokens=engine.spec.k + 1)
+    if hw is None:
+        leaf = jax.tree_util.tree_leaves(engine.params)[0]
+        hw = hw_for_device(min(leaf.devices(), key=lambda d: d.id))
     if prefix_cache is None:
         prefix_cache = PrefixCacheCfg(
             enabled=engine.radix is not None,
             block_tokens=engine.radix.block if engine.radix else 16,
             capacity_fraction=0.5)
     return InstanceCfg(
-        name=engine.name, hw=hw if hw is not None else ENGINE_HW,
+        name=engine.name, hw=hw,
         model=model,
         n_devices=engine.tp, role=engine.role,
         parallelism=ParallelismCfg(tp=engine.tp),

@@ -272,10 +272,11 @@ class ServingEngine:
                         f"has {moe_layer_count(cfg)}")
                 self.routing_trace = routing
                 hook = make_replay_hook(routing)
-        # kernel backend: resolve "auto" against the platform; pallas
-        # serves attention-only archs at tp=1 (its decode path is the
-        # paged slot-KV layout, which has no sharded variant yet) —
-        # "auto" falls back to reference elsewhere, "pallas" is loud
+        # kernel backend: resolve against the platform; pallas serves
+        # attention-only archs at tp=1 (its decode path is the paged
+        # slot-KV layout, which has no sharded variant yet).  A config
+        # the kernels cannot serve is an error: the reference path runs
+        # only when the config names it.
         from repro.configs.base import ATTN_MLP, ATTN_MOE
         from repro.kernels import resolve_backend
         backend, interpret = resolve_backend(cfg.kernels)
@@ -285,11 +286,9 @@ class ServingEngine:
             if bad or self.tp > 1:
                 why = f"tp={self.tp}" if self.tp > 1 else \
                     f"non-attention stages {bad}"
-                if cfg.kernels == "pallas":
-                    raise ValueError(
-                        f"kernels='pallas' does not support {why} on "
-                        f"{cfg.name!r}; use kernels='auto' to fall back")
-                backend, interpret = "reference", False
+                raise ValueError(
+                    f"kernels={cfg.kernels!r} cannot serve {why} on "
+                    f"{cfg.name!r}; set kernels='reference' explicitly")
         self.kernel_backend = backend
         self.pallas_interpret = interpret
         self.paged = backend == "pallas"
@@ -298,11 +297,14 @@ class ServingEngine:
                            kernel_backend=backend,
                            pallas_interpret=interpret, paged=self.paged,
                            page_size=self.page_size)
-        self.params = params if params is not None else self.model.init(
-            jax.random.PRNGKey(seed))
         self.max_batch = max_batch
         self.max_len = max_len
-        self.cache = self.model.init_cache(max_batch, max_len)
+        if self.tp > 1:
+            self._shard_over_mesh(params, seed)
+        else:
+            self.params = params if params is not None else \
+                self.model.init(jax.random.PRNGKey(seed))
+            self.cache = self.model.init_cache(max_batch, max_len)
         if self.paged:
             # page allocator: free-list over the shared pool, a host
             # numpy mirror of the device block table, and per-slot
@@ -315,14 +317,16 @@ class ServingEngine:
             self._table_np = np.full((max_batch, self._maxp),
                                      self._scratch, np.int32)
             self._slot_pages = [0] * max_batch
-        if self.tp > 1:
-            self._shard_over_mesh()
         self.slot_free = list(range(max_batch))
         self.radix = RealRadixCache() if prefix_cache else None
         self._jit_decode = jax.jit(self.model.decode)
         self._jit_prefill = jax.jit(self.model.prefill,
                                     static_argnames=())
-        self._jit_extend = jax.jit(self.model.extend)
+        # the extended (sub)cache is donated: a paged subcache shares the
+        # live page pools, so the extend writes them in place instead of
+        # copying a whole pool per chunk.  Callers adopt the returned
+        # cache through ``_write_slot``.
+        self._jit_extend = jax.jit(self.model.extend, donate_argnums=(1,))
         self._tokens_buf = np.zeros((max_batch, 1), np.int32)
         # speculative decoding: a nested mechanism-only draft engine
         # (same slot geometry, so draft slot i mirrors target slot i) and
@@ -353,33 +357,42 @@ class ServingEngine:
                 seed=spec.draft_seed)
             self._jit_verify = jax.jit(self.model.verify)
 
-    def _shard_over_mesh(self):
+    def _shard_over_mesh(self, params, seed: int):
         """Lay params + slot cache out over the (data=1, model=tp) mesh.
 
         Uses the same PartitionSpec rules as the production launcher
         (params: column/row TP; KV: heads or head_dim on the model axis),
         post-passed by ``fit_to_mesh`` so dims that do not divide the tp
-        degree are replicated explicitly.  The jits then pick the committed
-        shardings up from their inputs — no per-jit in_shardings needed.
+        degree are replicated explicitly.  Fresh params and the cache are
+        built directly in that layout (a model that needs tp devices does
+        not fit one); given params are moved into it.  The jits then pick
+        the committed shardings up from their inputs — no per-jit
+        in_shardings needed.
         """
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.launch import sharding as shd
         from repro.launch.mesh import make_engine_mesh
         self.mesh = make_engine_mesh(self.tp)
 
-        def place(tree, spec_tree):
+        def shardings(tree, spec_tree):
             fitted = shd.fit_to_mesh(spec_tree, tree, self.mesh)
-            shardings = jax.tree_util.tree_map(
+            return jax.tree_util.tree_map(
                 lambda s: NamedSharding(self.mesh, s), fitted,
                 is_leaf=lambda x: isinstance(x, P))
-            return jax.device_put(tree, shardings)
 
-        self.params = place(
-            self.params, shd.param_pspecs(self.params, model_size=self.tp))
-        self.cache = place(
-            self.cache, shd.cache_pspecs(self.cache, ("data",),
-                                         self.max_batch,
-                                         model_size=self.tp))
+        key = jax.random.PRNGKey(seed)
+        shapes = jax.eval_shape(self.model.init, key) if params is None \
+            else params
+        out = shardings(shapes, shd.param_pspecs(shapes, model_size=self.tp))
+        self.params = self.model.init(key, out_shardings=out) \
+            if params is None else jax.device_put(params, out)
+
+        def new_cache():
+            return self.model.init_cache(self.max_batch, self.max_len)
+        shapes = jax.eval_shape(new_cache)
+        self.cache = jax.jit(new_cache, out_shardings=shardings(
+            shapes, shd.cache_pspecs(shapes, ("data",), self.max_batch,
+                                     model_size=self.tp)))()
 
     def warmup(self, buckets=(16, 32, 64, 128, 256)):
         """Compile prefill/extend/decode at every bucket so measured
@@ -396,9 +409,11 @@ class ServingEngine:
             if self.radix is not None:
                 sub = self._slot_subcache(0, 16)
                 try:
-                    jax.block_until_ready(self._jit_extend(
-                        self.params, sub, pad,
-                        jnp.asarray([P], jnp.int32)))
+                    _, sub = self._jit_extend(self.params, sub, pad,
+                                              jnp.asarray([P], jnp.int32))
+                    self._write_slot(0, sub, 16)
+                    self._release_slot(0)
+                    jax.block_until_ready(self.cache)
                 except NotImplementedError:
                     pass
         jax.block_until_ready(self._jit_decode(
@@ -522,23 +537,19 @@ class ServingEngine:
         """A (B=1) view of one slot (full max_len buffers, real length)."""
         if self.paged:
             # zero-copy: the shared pools ARE the storage; the one-row
-            # table is the view.  ``extend`` on this subcache scatters
-            # straight into the slot's pages.
+            # table is the view.  The pools pass by reference (a jit
+            # returning them unchanged would copy each one), and
+            # ``extend`` on this subcache scatters straight into the
+            # slot's pages.
             fn = self._get_jit("subcache_paged", None)
             if fn is None:
-                def impl(cache, slot, length):
-                    sub = {}
-                    for key in cache:
-                        if key == "lengths":
-                            sub[key] = jnp.full((1,), length, jnp.int32)
-                        elif key == "block_table":
-                            sub[key] = cache[key][slot: slot + 1]
-                        else:
-                            sub[key] = cache[key]
-                    return sub
+                def impl(table, slot, length):
+                    return (table[slot: slot + 1],
+                            jnp.full((1,), length, jnp.int32))
                 fn = self._put_jit("subcache_paged", None,
                                    jax.jit(impl, static_argnums=(1,)))
-            return fn(self.cache, slot, length)
+            table, lengths = fn(self.cache["block_table"], slot, length)
+            return {**self.cache, "block_table": table, "lengths": lengths}
         fn = self._get_jit("subcache", None)
         if fn is None:
             def impl(cache, slot, length):
@@ -558,23 +569,12 @@ class ServingEngine:
     def _write_slot(self, slot: int, sub_cache, n: int):
         if self.paged:
             # the subcache's pools already hold the extend's writes
-            # (shared storage): adopt them wholesale — pure pass-through,
-            # jax forwards unmodified outputs without a copy — and bump
-            # the slot length.  No donation: warmup writes back an
-            # untouched subcache whose pools alias the live cache.
-            fn = self._get_jit("write_slot_paged", None)
-            if fn is None:
-                def impl(cache, sub, slot, n):
-                    out = dict(cache)
-                    for key in cache:
-                        if key in ("lengths", "block_table"):
-                            continue
-                        out[key] = sub[key]
-                    out["lengths"] = cache["lengths"].at[slot].set(n)
-                    return out
-                fn = self._put_jit("write_slot_paged", None, jax.jit(
-                    impl, static_argnums=(2,)))
-            self.cache = fn(self.cache, sub_cache, slot, n)
+            # (shared storage): adopt them by reference, as a jit
+            # returning them would copy each one, and bump the slot
+            # length.
+            self.cache = {**sub_cache,
+                          "block_table": self.cache["block_table"],
+                          "lengths": self.cache["lengths"].at[slot].set(n)}
             return
         fn = self._get_jit("write_slot", None)
         if fn is None:

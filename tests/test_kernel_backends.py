@@ -174,3 +174,22 @@ def test_sim_real_decision_parity_on_paged_engine():
     sim_dec = {n: i.decisions for n, i in sim_cluster.instances.items()}
     assert real["finished"] == sim["finished"] == 6
     assert real_dec == sim_dec
+
+
+def test_paged_chunk_extend_reuses_the_page_pools():
+    """A chunked-prefill extend on the paged engine allocates no page pool
+    of its own: the subcache shares the live pools, the extend donates
+    them, and the write-back adopts the result by reference."""
+    cfg = dataclasses.replace(get_config(ARCH), kernels="auto")
+    eng = ServingEngine(cfg, max_batch=2, max_len=128)
+    eng.ensure_capacity(1, 48)
+    stages = [k for k in eng.cache if k.startswith("stage")]
+    pools = [eng.cache[k]["k_pages"] for k in stages]
+    sub = eng._slot_subcache(1, 16)
+    assert all(sub[k]["k_pages"] is p for k, p in zip(stages, pools))
+    _, new = eng._jit_extend(eng.params, sub, jnp.ones((1, 32), jnp.int32),
+                             jnp.asarray([32], jnp.int32))
+    assert all(p.is_deleted() for p in pools)
+    eng._write_slot(1, new, 48)
+    assert all(eng.cache[k]["k_pages"] is new[k]["k_pages"] for k in stages)
+    assert eng.cache["lengths"].tolist() == [0, 48]
