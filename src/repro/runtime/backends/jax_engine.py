@@ -28,6 +28,7 @@ from repro.core.config import InstanceCfg
 from repro.core.memory import MemoryModel
 from repro.core.request import SimRequest
 from repro.obs.events import SPEC_STEP
+from repro.obs.spans import bind_profiler, span
 from repro.runtime.backend import KvHandoff
 from repro.runtime.prefix_cache import MatchResult
 from repro.runtime.scheduler import ScheduledWork
@@ -39,6 +40,8 @@ class JaxBackend:
     def __init__(self, engine, cfg: InstanceCfg):
         # late imports: the sim path must not pay for jax
         import jax  # noqa: F401
+        # host spans of the served path go on the profiler's clock
+        bind_profiler()
         self.eng = engine
         self.cfg = cfg
         self.memory = MemoryModel(cfg)
@@ -227,7 +230,8 @@ class JaxBackend:
                 self._decode_step(decodes)
         for w in prefills:
             self._prefill_chunk(w)
-        jax.block_until_ready(self.eng.cache)
+        with span("backend.sync"):
+            jax.block_until_ready(self.eng.cache)
         self._iterations += 1
         latency = time.perf_counter() - t0 + self._carry_s
         self._carry_s = 0.0
@@ -240,46 +244,52 @@ class JaxBackend:
         import jax.numpy as jnp
         from repro.serve.sampler import greedy
         eng = self.eng
-        tokens = eng._tokens_buf
-        for w in decodes:
-            # paged KV: the decode writes each scheduled slot's new token
-            # at its old length — make sure that page exists (no-op on
-            # the contiguous layout)
-            slot = self._slot[w.request.req_id]
-            eng.ensure_capacity(slot, self._len[slot] + 1)
-        if self.routing is not None or self.eng.model.routing_hook \
-                is not None:
-            # routing-hook runs: mark every NON-scheduled slot (free, or
-            # occupied mid-prefill) with the sentinel token -1 so the
-            # model's decode mask excludes its row from MoE recording and
-            # capacity — the full-buffer decode computes it regardless,
-            # but it is not workload routing.  The engine buffer itself
-            # is left untouched (mid-prefill slots keep their pending
-            # first token).
-            tokens = tokens.copy()
-            scheduled_slots = {self._slot[w.request.req_id]
-                               for w in decodes}
-            for slot in range(eng.max_batch):
-                if slot not in scheduled_slots:
-                    tokens[slot, 0] = -1
-        logits, eng.cache = eng._jit_decode(
-            eng.params, eng.cache, jnp.asarray(tokens))
-        nxt = np.asarray(greedy(logits, eng.cfg.vocab))
-        scheduled = set()
-        for w in decodes:
-            slot = self._slot[w.request.req_id]
-            eng._tokens_buf[slot, 0] = int(nxt[slot, 0])
-            self.out_tokens.setdefault(w.request.req_id, []).append(
-                int(nxt[slot, 0]))
-            if self.expert_load is not None:
-                # the decode wrote this slot's token at KV index _len
-                self._routed_pos.append(self._len[slot])
-            self._len[slot] += 1
-            scheduled.add(slot)
-        hooked = self.routing is not None \
-            or eng.model.routing_hook is not None
-        if scheduled != set(self._len) \
-                or (hooked and len(self._len) < eng.max_batch):
+        rows = len(decodes)
+        with span("backend.prepare", rows=rows):
+            tokens = eng._tokens_buf
+            for w in decodes:
+                # paged KV: the decode writes each scheduled slot's new
+                # token at its old length — make sure that page exists
+                # (no-op on the contiguous layout)
+                slot = self._slot[w.request.req_id]
+                eng.ensure_capacity(slot, self._len[slot] + 1)
+            if self.routing is not None or self.eng.model.routing_hook \
+                    is not None:
+                # routing-hook runs: mark every NON-scheduled slot (free,
+                # or occupied mid-prefill) with the sentinel token -1 so
+                # the model's decode mask excludes its row from MoE
+                # recording and capacity — the full-buffer decode
+                # computes it regardless, but it is not workload routing.
+                # The engine buffer itself is left untouched (mid-prefill
+                # slots keep their pending first token).
+                tokens = tokens.copy()
+                scheduled_slots = {self._slot[w.request.req_id]
+                                   for w in decodes}
+                for slot in range(eng.max_batch):
+                    if slot not in scheduled_slots:
+                        tokens[slot, 0] = -1
+            tokens = jnp.asarray(tokens)
+        with span("backend.launch", program="decode", rows=rows):
+            logits, eng.cache = eng._jit_decode(eng.params, eng.cache,
+                                                tokens)
+        with span("backend.sample", rows=rows):
+            nxt = np.asarray(greedy(logits, eng.cfg.vocab))
+            scheduled = set()
+            for w in decodes:
+                slot = self._slot[w.request.req_id]
+                eng._tokens_buf[slot, 0] = int(nxt[slot, 0])
+                self.out_tokens.setdefault(w.request.req_id, []).append(
+                    int(nxt[slot, 0]))
+                if self.expert_load is not None:
+                    # the decode wrote this slot's token at KV index _len
+                    self._routed_pos.append(self._len[slot])
+                self._len[slot] += 1
+                scheduled.add(slot)
+            hooked = self.routing is not None \
+                or eng.model.routing_hook is not None
+            refresh = scheduled != set(self._len) \
+                or (hooked and len(self._len) < eng.max_batch)
+        if refresh:
             # the full-buffer decode bumped every slot's length; restore
             # the authoritative lengths of mid-prefill / unscheduled
             # slots.  With a MoE routing hook installed, ALSO zero the
@@ -292,10 +302,11 @@ class JaxBackend:
             # traces and letting empty slots consume real tokens' expert
             # capacity under forced replay.  Unhooked engines keep the
             # old fast path.
-            lengths = np.zeros((eng.max_batch,), np.int32)
-            for s, n in self._len.items():
-                lengths[s] = n
-            eng.cache["lengths"] = jnp.asarray(lengths)
+            with span("backend.prepare", rows=rows):
+                lengths = np.zeros((eng.max_batch,), np.int32)
+                for s, n in self._len.items():
+                    lengths[s] = n
+                eng.cache["lengths"] = jnp.asarray(lengths)
 
     def _spec_decode_step(self, decodes: List[ScheduledWork], now: float):
         """One speculative iteration for the scheduled decode set: the
@@ -332,10 +343,15 @@ class JaxBackend:
                 P = _bucket(max(len(hist), 1))
                 pad = np.zeros((1, P), np.int32)
                 pad[0, :len(hist)] = np.asarray(hist, np.int32)
-                _, c1 = dr._jit_prefill(
-                    dr.params, jnp.asarray(pad),
-                    lengths=jnp.asarray([len(hist)], jnp.int32))
-                dr._write_slot_from_prefill(slot, c1, len(hist))
+                rid = w.request.req_id
+                with span("backend.launch", program="draft.prefill",
+                          req=rid, tokens=len(hist)):
+                    _, c1 = dr._jit_prefill(
+                        dr.params, jnp.asarray(pad),
+                        lengths=jnp.asarray([len(hist)], jnp.int32))
+                with span("backend.launch", program="draft.write_prefill",
+                          req=rid, tokens=len(hist)):
+                    dr._write_slot_from_prefill(slot, c1, len(hist))
                 self._draft_len[slot] = len(hist)
 
         # tail clamp: a request with r = output_len - generated tokens
@@ -353,33 +369,42 @@ class JaxBackend:
         # paged KV: verify writes the pending token + k_eff drafts at
         # positions [len, len + k_eff]; the draft's k_step + 1 decodes
         # walk one position per call (no-ops on contiguous layouts)
-        for w in decodes:
-            slot = self._slot[w.request.req_id]
-            eng.ensure_capacity(slot, self._len[slot] + k_eff[slot] + 1)
-            dr.ensure_capacity(slot,
-                               self._draft_len.get(slot, 0) + k_step + 1)
+        rows = len(decodes)
+        with span("backend.prepare", rows=rows):
+            for w in decodes:
+                slot = self._slot[w.request.req_id]
+                eng.ensure_capacity(slot,
+                                    self._len[slot] + k_eff[slot] + 1)
+                dr.ensure_capacity(
+                    slot, self._draft_len.get(slot, 0) + k_step + 1)
 
         # 2. propose: k_step + 1 sequential full-buffer draft decodes
         cur = np.maximum(np.asarray(eng._tokens_buf), 0)
         drafts = np.zeros((eng.max_batch, k_step), np.int32)
         for j in range(k_step + 1):
-            dlogits, dr.cache = dr._jit_decode(dr.params, dr.cache,
-                                               jnp.asarray(cur))
-            cur = np.asarray(greedy(dlogits, eng.cfg.vocab))
+            with span("backend.launch", program="draft.decode", rows=rows):
+                dlogits, dr.cache = dr._jit_decode(dr.params, dr.cache,
+                                                   jnp.asarray(cur))
+            with span("backend.sample", rows=rows):
+                cur = np.asarray(greedy(dlogits, eng.cfg.vocab))
             if j < k_step:
                 drafts[:, j] = cur[:, 0]
 
         # 3. batched target verification over [pending, d1..dk_eff]
-        vt = np.concatenate(
-            [np.maximum(np.asarray(eng._tokens_buf), 0), drafts], axis=1)
-        n_new = np.zeros((eng.max_batch,), np.int32)
-        for w in decodes:
-            slot = self._slot[w.request.req_id]
-            n_new[slot] = k_eff[slot] + 1
-        vlogits, eng.cache = eng._jit_verify(
-            eng.params, eng.cache, jnp.asarray(vt), jnp.asarray(n_new))
-        target = np.asarray(greedy(vlogits, eng.cfg.vocab))  # (B, k+1)
-        matched = accept_length(drafts, target)
+        with span("backend.prepare", rows=rows):
+            vt = np.concatenate(
+                [np.maximum(np.asarray(eng._tokens_buf), 0), drafts],
+                axis=1)
+            n_new = np.zeros((eng.max_batch,), np.int32)
+            for w in decodes:
+                slot = self._slot[w.request.req_id]
+                n_new[slot] = k_eff[slot] + 1
+        with span("backend.launch", program="verify", rows=rows):
+            vlogits, eng.cache = eng._jit_verify(
+                eng.params, eng.cache, jnp.asarray(vt), jnp.asarray(n_new))
+        with span("backend.sample", rows=rows):
+            target = np.asarray(greedy(vlogits, eng.cfg.vocab))  # (B, k+1)
+            matched = accept_length(drafts, target)
 
         # 4. acceptance + rollback per scheduled slot
         for w in decodes:
@@ -427,14 +452,15 @@ class JaxBackend:
         # scheduled slots to the full window; draft decodes bumped every
         # row.  Unaccepted rows become dead weight overwritten by the
         # next write at the same indices.
-        lengths = np.zeros((eng.max_batch,), np.int32)
-        for s, n in self._len.items():
-            lengths[s] = n
-        eng.cache["lengths"] = jnp.asarray(lengths)
-        dlen = np.zeros((eng.max_batch,), np.int32)
-        for s, n in self._draft_len.items():
-            dlen[s] = n
-        dr.cache["lengths"] = jnp.asarray(dlen)
+        with span("backend.prepare", rows=rows):
+            lengths = np.zeros((eng.max_batch,), np.int32)
+            for s, n in self._len.items():
+                lengths[s] = n
+            eng.cache["lengths"] = jnp.asarray(lengths)
+            dlen = np.zeros((eng.max_batch,), np.int32)
+            for s, n in self._draft_len.items():
+                dlen[s] = n
+            dr.cache["lengths"] = jnp.asarray(dlen)
 
     def decode_emitted(self, req: SimRequest) -> int:
         """Tokens the last decode step emitted for ``req`` (1 for vanilla
@@ -447,54 +473,73 @@ class JaxBackend:
         from repro.serve.sampler import greedy
         eng = self.eng
         req = w.request
-        toks = self._prompt(req)
-        slot = self._slot.get(req.req_id)
-        if slot is None:
-            slot = eng.slot_free.pop()
-            self._slot[req.req_id] = slot
-            self._len[slot] = 0
-            self._hist[slot] = []
-            self._draft_len.pop(slot, None)
-            restore = self._restore.pop(req.req_id, None)
-            if restore is not None and req.cached_prefix > 0:
-                payload, length = restore
-                length = min(length, req.cached_prefix)
-                # SSD-tier stubs load here, inside execute()'s timed
-                # region, so the disk read lands on the virtual clock
-                payload = eng.radix.resolve(payload)
-                eng._restore_slot(slot, payload, length)
-                self._len[slot] = length
-                self._hist[slot] = list(toks[:length])
-        start = self._len[slot]
-        end = min(start + w.tokens, len(toks))
-        chunk = toks[start:end]
+        rid = req.req_id
+        with span("backend.prepare", req=rid, tokens=w.tokens):
+            toks = self._prompt(req)
+            slot = self._slot.get(rid)
+            if slot is None:
+                slot = eng.slot_free.pop()
+                self._slot[rid] = slot
+                self._len[slot] = 0
+                self._hist[slot] = []
+                self._draft_len.pop(slot, None)
+                restore = self._restore.pop(rid, None)
+                if restore is not None and req.cached_prefix > 0:
+                    payload, length = restore
+                    length = min(length, req.cached_prefix)
+                    # SSD-tier stubs load here, inside execute()'s timed
+                    # region, so the disk read lands on the virtual clock
+                    payload = eng.radix.resolve(payload)
+                    with span("backend.launch", program="restore", req=rid,
+                              tokens=length):
+                        eng._restore_slot(slot, payload, length)
+                    self._len[slot] = length
+                    self._hist[slot] = list(toks[:length])
+            start = self._len[slot]
+            end = min(start + w.tokens, len(toks))
+            chunk = toks[start:end]
+            if chunk:
+                n = len(chunk)
+                P = _bucket(n)
+                pad = np.zeros((1, P), np.int32)
+                pad[0, :n] = np.asarray(chunk, np.int32)
+                n_new = jnp.asarray([n], jnp.int32)
+                pad = jnp.asarray(pad)
+                if start > 0:
+                    eng.ensure_capacity(slot, start + n)
         logits = None
         if chunk:
-            P = _bucket(len(chunk))
-            pad = np.zeros((1, P), np.int32)
-            pad[0, :len(chunk)] = np.asarray(chunk, np.int32)
-            n_new = jnp.asarray([len(chunk)], jnp.int32)
             if start == 0:
-                logits, c1 = eng._jit_prefill(eng.params, jnp.asarray(pad),
-                                              lengths=n_new)
-                eng._write_slot_from_prefill(slot, c1, len(chunk))
+                with span("backend.launch", program="prefill", req=rid,
+                          tokens=n):
+                    logits, c1 = eng._jit_prefill(eng.params, pad,
+                                                  lengths=n_new)
+                with span("backend.launch", program="write_prefill",
+                          req=rid, tokens=n):
+                    eng._write_slot_from_prefill(slot, c1, n)
             else:
-                eng.ensure_capacity(slot, start + len(chunk))
-                sub = eng._slot_subcache(slot, start)
-                logits, new_sub = eng._jit_extend(eng.params, sub,
-                                                  jnp.asarray(pad), n_new)
-                eng._write_slot(slot, new_sub, start + len(chunk))
+                with span("backend.launch", program="subcache", req=rid,
+                          tokens=start):
+                    sub = eng._slot_subcache(slot, start)
+                with span("backend.launch", program="extend", req=rid,
+                          tokens=n):
+                    logits, new_sub = eng._jit_extend(eng.params, sub, pad,
+                                                      n_new)
+                with span("backend.launch", program="write_slot", req=rid,
+                          tokens=start + n):
+                    eng._write_slot(slot, new_sub, start + n)
             if self.expert_load is not None:
                 # the chunk's tokens occupy KV positions [start, start+n)
-                self._routed_pos.extend(range(start, start + len(chunk)))
-            self._len[slot] = start + len(chunk)
+                self._routed_pos.extend(range(start, start + n))
+            self._len[slot] = start + n
             self._hist[slot].extend(int(t) for t in chunk)
         if self._len[slot] >= len(toks) and logits is not None:
             # prompt complete: the last chunk's logits give the first token
-            first = int(np.asarray(greedy(logits, eng.cfg.vocab))[0, 0])
-            eng._tokens_buf[slot, 0] = first
-            self.out_tokens.setdefault(req.req_id, []).append(first)
-            self._emit[slot] = 1
+            with span("backend.sample", req=rid):
+                first = int(np.asarray(greedy(logits, eng.cfg.vocab))[0, 0])
+                eng._tokens_buf[slot, 0] = first
+                self.out_tokens.setdefault(rid, []).append(first)
+                self._emit[slot] = 1
 
     # ---- prefix cache payloads ----
     def on_prefix_hit(self, req: SimRequest, match: MatchResult,
@@ -526,8 +571,10 @@ class JaxBackend:
         if blk > 0:
             # device-resident entry (hot tier): the gathered jax arrays
             # stay on device until the runtime demotes them
-            self.eng.radix.insert(
-                toks, self.eng._export_slot(slot, blk, to_host=False))
+            with span("backend.launch", program="export", req=req.req_id,
+                      tokens=blk):
+                kv = self.eng._export_slot(slot, blk, to_host=False)
+            self.eng.radix.insert(toks, kv)
         self._carry_s += time.perf_counter() - t0
 
     def on_tier_transfer(self, src: str, dst: str, n_bytes: float,
@@ -581,14 +628,17 @@ class JaxBackend:
         self._draft_len.pop(slot, None)
         self._emit.pop(slot, None)
         self._steps.pop(slot, None)
-        self.eng._release_slot(slot)
+        with span("backend.release", req=req.req_id):
+            self.eng._release_slot(slot)
 
     # ---- P/D handoff ----
     def export_kv(self, req: SimRequest) -> KvHandoff:
         t0 = time.perf_counter()
         slot = self._slot[req.req_id]
         length = self._len[slot]
-        kv = self.eng._export_slot(slot, length)
+        with span("backend.launch", program="export", req=req.req_id,
+                  tokens=length):
+            kv = self.eng._export_slot(slot, length)
         first = int(self.eng._tokens_buf[slot, 0])
         nbytes = float(sum(
             np.asarray(leaf).nbytes
@@ -605,7 +655,9 @@ class JaxBackend:
         slot = self.eng.slot_free.pop()
         self._slot[req.req_id] = slot
         p = handoff.payload
-        self.eng._restore_slot(slot, p["kv"], p["len"])
+        with span("backend.launch", program="restore", req=req.req_id,
+                  tokens=p["len"]):
+            self.eng._restore_slot(slot, p["kv"], p["len"])
         self.eng._tokens_buf[slot, 0] = p["first"]
         self._len[slot] = p["len"]
         # spec bookkeeping: the transferred KV holds exactly the (possibly

@@ -26,6 +26,7 @@ from repro.core.network import NetworkModel
 from repro.core.request import QUEUED, SimRequest
 from repro.core.trace import Trace, TraceRegistry
 from repro.obs.events import ARRIVAL, FAIL, PD_EXPORT, PREEMPT, SCALE
+from repro.obs.spans import span
 from repro.runtime.backend import ExecutionBackend
 from repro.runtime.instance import RuntimeInstance
 from repro.runtime.prefix_cache import RadixPrefixCache
@@ -205,13 +206,14 @@ class ServingRuntime:
                 r.arrival, lambda s=sim: self._arrive(s), tag="arrival")
 
     def _arrive(self, req: SimRequest):
-        obs = self.obs
-        if obs is not None:
-            obs.emit(self.queue.now, ARRIVAL, req=req.req_id,
-                     tenant=req.tenant,
-                     payload={"prompt": req.prompt_len,
-                              "output": req.output_len})
-        self.router.dispatch(req, self.queue.now)
+        with span("runtime.arrive", req=req.req_id):
+            obs = self.obs
+            if obs is not None:
+                obs.emit(self.queue.now, ARRIVAL, req=req.req_id,
+                         tenant=req.tenant,
+                         payload={"prompt": req.prompt_len,
+                                  "output": req.output_len})
+            self.router.dispatch(req, self.queue.now)
 
     # ---- failures / elastic scaling ----
     def inject_failure(self, t: float, instance: str,

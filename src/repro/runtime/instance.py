@@ -23,6 +23,7 @@ from repro.core.request import (DECODING, FINISHED, QUEUED,
                                 TRANSFERRING, SimRequest)
 from repro.obs.events import (ADMIT, FINISH, ITER, KV_RESTORE, KV_TIER,
                               PD_ADMIT, PREEMPT)
+from repro.obs.spans import span
 from repro.runtime.backend import ExecutionBackend, KvHandoff
 from repro.runtime.prefix_cache import RadixPrefixCache
 from repro.runtime.scheduler import BatchScheduler, ScheduledWork
@@ -144,15 +145,16 @@ class RuntimeInstance:
             self._start_iteration()
 
     def _start_iteration(self):
-        work = self.scheduler.next_batch()
-        if not work:
-            self.busy = False
-            return
-        self.busy = True
-        if self._maybe_fast_forward(work):
-            return
-        self.decisions.append(
-            tuple((w.request.req_id, w.phase, w.tokens) for w in work))
+        with span("runtime.schedule"):
+            work = self.scheduler.next_batch()
+            if not work:
+                self.busy = False
+                return
+            self.busy = True
+            if self._maybe_fast_forward(work):
+                return
+            self.decisions.append(
+                tuple((w.request.req_id, w.phase, w.tokens) for w in work))
         latency = self.backend.execute(work, self.queue.now)
         self.iterations += 1
         tokens = sum(w.tokens for w in work)
@@ -176,6 +178,13 @@ class RuntimeInstance:
                           latency: float = 0.0):
         if not self.alive:
             return
+        with span("runtime.finish", rows=len(work)):
+            self._settle_iteration(work, latency)
+        self._start_iteration()
+
+    def _settle_iteration(self, work: List[ScheduledWork], latency: float):
+        """Apply a finished iteration: watermark, prefill progress,
+        emitted tokens, finishes, and admission of parked decodes."""
         now = self.queue.now
         self.kv_watermark.append(
             (now, self.mem.total_blocks - self.mem.free_blocks,
@@ -218,7 +227,6 @@ class RuntimeInstance:
                     self._finish_request(req)
         self._drain_pending_decode()
         self.busy = False
-        self._start_iteration()
 
     # ---- decode fast-forward ----
     #: max steps per bulk event — bounds the synthesized timeline arrays

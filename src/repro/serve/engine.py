@@ -31,6 +31,7 @@ import numpy as np
 
 from repro.configs import ArchConfig
 from repro.models import Model
+from repro.obs.spans import span
 
 
 @dataclasses.dataclass
@@ -451,7 +452,9 @@ class ServingEngine:
         self._push_table()
 
     def _push_table(self):
-        self.cache["block_table"] = jnp.asarray(self._table_np)
+        with span("backend.prepare",
+                  pages=self._n_pages - 1 - len(self._page_free)):
+            self.cache["block_table"] = jnp.asarray(self._table_np)
 
     def _free_pages(self, slot: int):
         if not self.paged or not self._slot_pages[slot]:
@@ -486,7 +489,7 @@ class ServingEngine:
             if fn is None:
                 ps, maxp, scratch = self.page_size, self._maxp, self._scratch
 
-                def impl(cache, cache1, slot, n):
+                def write_prefill_paged(cache, cache1, slot, n):
                     row = cache["block_table"][slot]
                     pos = jnp.arange(P)
                     pidx = pos // ps
@@ -506,12 +509,13 @@ class ServingEngine:
                     out["lengths"] = cache["lengths"].at[slot].set(n)
                     return out
                 fn = self._put_jit("write_prefill_paged", P, jax.jit(
-                    impl, donate_argnums=(0,), static_argnums=(2,)))
+                    write_prefill_paged, donate_argnums=(0,),
+                    static_argnums=(2,)))
             self.cache = fn(self.cache, cache1, slot, n)
             return
         fn = self._get_jit("write_prefill", P)
         if fn is None:
-            def impl(cache, cache1, slot, n):
+            def write_prefill(cache, cache1, slot, n):
                 def write(big, small):
                     if big.ndim >= 2 and small.shape[1] == 1:
                         if big.ndim >= 3 and small.ndim >= 3 \
@@ -530,7 +534,7 @@ class ServingEngine:
                 out["lengths"] = cache["lengths"].at[slot].set(n)
                 return out
             fn = self._put_jit("write_prefill", P, jax.jit(
-                impl, donate_argnums=(0,), static_argnums=(2,)))
+                write_prefill, donate_argnums=(0,), static_argnums=(2,)))
         self.cache = fn(self.cache, cache1, slot, n)
 
     def _slot_subcache(self, slot: int, length: int):
@@ -543,16 +547,17 @@ class ServingEngine:
             # slot's pages.
             fn = self._get_jit("subcache_paged", None)
             if fn is None:
-                def impl(table, slot, length):
+                def subcache_paged(table, slot, length):
                     return (table[slot: slot + 1],
                             jnp.full((1,), length, jnp.int32))
                 fn = self._put_jit("subcache_paged", None,
-                                   jax.jit(impl, static_argnums=(1,)))
+                                   jax.jit(subcache_paged,
+                                           static_argnums=(1,)))
             table, lengths = fn(self.cache["block_table"], slot, length)
             return {**self.cache, "block_table": table, "lengths": lengths}
         fn = self._get_jit("subcache", None)
         if fn is None:
-            def impl(cache, slot, length):
+            def subcache(cache, slot, length):
                 def take(big):
                     return big[:, slot: slot + 1] if big.ndim >= 2 else big
                 sub = {}
@@ -563,7 +568,7 @@ class ServingEngine:
                         sub[key] = jax.tree_util.tree_map(take, cache[key])
                 return sub
             fn = self._put_jit("subcache", None,
-                               jax.jit(impl, static_argnums=(1,)))
+                               jax.jit(subcache, static_argnums=(1,)))
         return fn(self.cache, slot, length)
 
     def _write_slot(self, slot: int, sub_cache, n: int):
@@ -578,7 +583,7 @@ class ServingEngine:
             return
         fn = self._get_jit("write_slot", None)
         if fn is None:
-            def impl(cache, sub, slot, n):
+            def write_slot(cache, sub, slot, n):
                 def write(big, small):
                     return big.at[:, slot: slot + 1].set(small) \
                         if big.ndim >= 2 else big
@@ -591,7 +596,7 @@ class ServingEngine:
                 out["lengths"] = cache["lengths"].at[slot].set(n)
                 return out
             fn = self._put_jit("write_slot", None, jax.jit(
-                impl, donate_argnums=(0,), static_argnums=(2,)))
+                write_slot, donate_argnums=(0,), static_argnums=(2,)))
         self.cache = fn(self.cache, sub_cache, slot, n)
 
     def _export_slot(self, slot: int, length: int,
@@ -610,7 +615,7 @@ class ServingEngine:
                 ps, maxp = self.page_size, self._maxp
                 npg = min(-(-blen // ps), maxp)
 
-                def impl(cache, slot):
+                def export_paged(cache, slot):
                     pages = cache["block_table"][slot, :npg]
                     out = {}
                     for key in cache:
@@ -626,7 +631,8 @@ class ServingEngine:
                             [:, :blen]}
                     return out
                 fn = self._put_jit("export_paged", blen,
-                                   jax.jit(impl, static_argnums=(1,)))
+                                   jax.jit(export_paged,
+                                           static_argnums=(1,)))
             dev = fn(self.cache, slot)
             out = jax.tree_util.tree_map(np.asarray, dev) if to_host \
                 else dict(dev)
@@ -635,7 +641,7 @@ class ServingEngine:
             return out
         fn = self._get_jit("export", blen)
         if fn is None:
-            def impl(cache, slot):
+            def export(cache, slot):
                 def take(big):
                     if big.ndim >= 3 and big.shape[2] == self.max_len:
                         return jax.lax.dynamic_slice_in_dim(
@@ -646,7 +652,7 @@ class ServingEngine:
                 return {key: jax.tree_util.tree_map(take, cache[key])
                         for key in cache if key != "lengths"}
             fn = self._put_jit("export", blen,
-                               jax.jit(impl, static_argnums=(1,)))
+                               jax.jit(export, static_argnums=(1,)))
         dev = fn(self.cache, slot)
         out = jax.tree_util.tree_map(np.asarray, dev) if to_host \
             else dict(dev)
@@ -673,7 +679,7 @@ class ServingEngine:
             if fn is None:
                 ps, maxp, scratch = self.page_size, self._maxp, self._scratch
 
-                def impl(cache, kv, slot, n):
+                def restore_paged(cache, kv, slot, n):
                     row = cache["block_table"][slot]
                     pos = jnp.arange(blen)
                     pidx = pos // ps
@@ -693,13 +699,14 @@ class ServingEngine:
                     out["lengths"] = cache["lengths"].at[slot].set(n)
                     return out
                 fn = self._put_jit("restore_paged", blen, jax.jit(
-                    impl, donate_argnums=(0,), static_argnums=(2,)))
+                    restore_paged, donate_argnums=(0,),
+                    static_argnums=(2,)))
             kvdev = {k: v for k, v in kv.items() if not k.startswith("_")}
             self.cache = fn(self.cache, kvdev, slot, length)
             return
         fn = self._get_jit("restore", blen)
         if fn is None:
-            def impl(cache, kv, slot, n):
+            def restore(cache, kv, slot, n):
                 def write(big, small):
                     if big.ndim >= 3 and big.shape[2] == self.max_len \
                             and small.ndim >= 2 and small.shape[1] == blen:
@@ -716,6 +723,6 @@ class ServingEngine:
                 out["lengths"] = cache["lengths"].at[slot].set(n)
                 return out
             fn = self._put_jit("restore", blen, jax.jit(
-                impl, donate_argnums=(0,), static_argnums=(2,)))
+                restore, donate_argnums=(0,), static_argnums=(2,)))
         kvdev = {k: v for k, v in kv.items() if not k.startswith("_")}
         self.cache = fn(self.cache, kvdev, slot, length)
