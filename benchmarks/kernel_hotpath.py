@@ -92,22 +92,24 @@ def run(reps: int = 5, seed: int = 0):
     # block table) ----
     ps, maxp, nb = 16, 8, 4
     n_pages = nb * maxp + 1
-    kp, vp = rand(n_pages, ps, KV, dh), rand(n_pages, ps, KV, dh)
+    kp = rand(1, n_pages, ps, KV * dh)
+    vp = rand(1, n_pages, ps, KV * dh)
     table = jnp.asarray(np.random.default_rng(seed).permutation(
         nb * maxp)[:nb * maxp].reshape(nb, maxp), jnp.int32)
     dlen = jnp.array([1, ps, ps + 1, maxp * ps], jnp.int32)  # page edges
     qd = rand(nb, H, dh)
     rows.append(_case(
         "paged_decode_ragged",
-        lambda: paged_attention(qd, kp, vp, table, dlen, page_size=ps),
-        lambda: paged_attention_ref(qd, kp, vp, table, dlen, page_size=ps),
+        lambda: paged_attention(qd, kp, vp, table, dlen, 0, page_size=ps),
+        lambda: paged_attention_ref(qd, kp, vp, table, dlen, 0,
+                                    page_size=ps),
         reps))
     rows.append(_case(
         "paged_decode_window",
-        lambda: paged_attention(qd, kp, vp, table, dlen, page_size=ps,
+        lambda: paged_attention(qd, kp, vp, table, dlen, 0, page_size=ps,
                                 window=win),
-        lambda: paged_attention_ref(qd, kp, vp, table, dlen, page_size=ps,
-                                    window=win),
+        lambda: paged_attention_ref(qd, kp, vp, table, dlen, 0,
+                                    page_size=ps, window=win),
         reps))
 
     # ---- extend through the same paged kernel (chunked prefill) ----
@@ -117,10 +119,10 @@ def run(reps: int = 5, seed: int = 0):
     elen = jnp.minimum(start + Se, maxp * ps)
     rows.append(_case(
         "paged_extend",
-        lambda: paged_attention(qe, kp, vp, table, elen, page_size=ps,
+        lambda: paged_attention(qe, kp, vp, table, elen, 0, page_size=ps,
                                 start=start),
-        lambda: paged_attention_ref(qe, kp, vp, table, elen, page_size=ps,
-                                    start=start),
+        lambda: paged_attention_ref(qe, kp, vp, table, elen, 0,
+                                    page_size=ps, start=start),
         reps))
 
     # ---- fused MoE grouped matmul (uneven groups incl. zero-size) ----

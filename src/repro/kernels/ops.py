@@ -79,21 +79,24 @@ def flash_attention(q, k, v, lengths=None, window=None, *, bq: int = 128,
 
 
 @functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
-def paged_attention(q, k_pages, v_pages, block_table, lengths, *,
+def paged_attention(q, k_pages, v_pages, block_table, lengths, layer, *,
                     page_size: int, start=None, window=None,
                     interpret: Optional[bool] = None):
     """Decode: q (B,H,dh), one query per sequence at position length-1.
     Extend: q (B,S,H,dh) with ``start`` (B,), queries at start..start+S-1.
-    k_pages/v_pages: (P,ps,KV,dh); block_table: (B,maxp) int32;
-    ``window`` as in flash_attention."""
+    k_pages/v_pages: (L,P,ps,KV*dh), the stacked pools of L layers (a
+    single pool is L = 1); ``layer``: scalar index of the layer read;
+    block_table: (B,maxp) int32; ``window`` as in flash_attention."""
     return paged_attention_pallas(q, k_pages, v_pages, block_table, lengths,
-                                  page_size=page_size, start=start,
+                                  layer, page_size=page_size, start=start,
                                   window=window,
                                   interpret=_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("bc", "interpret"))
-def moe_gmm(x, w, group_sizes, *, bc: int = 128,
+def moe_gmm(x, w, group_sizes, layer=None, *, bc: int = 128,
             interpret: Optional[bool] = None):
-    return moe_gmm_pallas(x, w, group_sizes, bc=bc,
+    """x: (E,C,d); w: (E,d,f), or the stacked (L,E,d,f) of L layers with
+    ``layer`` the scalar index of the layer read; group_sizes: (E,)."""
+    return moe_gmm_pallas(x, w, group_sizes, layer, bc=bc,
                           interpret=_interpret(interpret))

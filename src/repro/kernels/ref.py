@@ -32,21 +32,24 @@ def flash_attention_ref(q, k, v, *, causal: bool = True, lengths=None,
     return o.reshape(B, S, H, dh).astype(q.dtype)
 
 
-def paged_attention_ref(q, k_pages, v_pages, block_table, lengths, *,
+def paged_attention_ref(q, k_pages, v_pages, block_table, lengths, layer, *,
                         page_size: int, start=None, window=None):
     """q: (B,H,dh) decode or (B,S,H,dh) extend (with ``start``);
-    k/v_pages: (P,ps,KV,dh); block_table: (B,maxp) int32; lengths: (B,)."""
+    k/v_pages: (L,P,ps,KV*dh) stacked pools, ``layer`` the one read;
+    block_table: (B,maxp) int32; lengths: (B,)."""
     squeeze = q.ndim == 3
     if squeeze:
         q = q[:, None]
     B, S, H, dh = q.shape
-    P, ps, KV, _ = k_pages.shape
+    ps = k_pages.shape[2]
+    KV = k_pages.shape[3] // dh
     G = H // KV
     maxp = block_table.shape[1]
     if start is None:
         start = jnp.maximum(lengths - 1, 0)
-    kg = k_pages[block_table.reshape(-1)].reshape(B, maxp * ps, KV, dh)
-    vg = v_pages[block_table.reshape(-1)].reshape(B, maxp * ps, KV, dh)
+    pages = block_table.reshape(-1)
+    kg = k_pages[layer, pages].reshape(B, maxp * ps, KV, dh)
+    vg = v_pages[layer, pages].reshape(B, maxp * ps, KV, dh)
     qr = q.reshape(B, S, KV, G, dh).astype(jnp.float32) * dh ** -0.5
     s = jnp.einsum("bskgd,bjkd->bskgj", qr, kg.astype(jnp.float32))
     q_pos = start[:, None] + jnp.arange(S)[None, :]          # (B, S)
@@ -62,8 +65,11 @@ def paged_attention_ref(q, k_pages, v_pages, block_table, lengths, *,
     return o[:, 0] if squeeze else o
 
 
-def moe_gmm_ref(x, w, group_sizes):
-    """Grouped matmul: x: (E,C,d); w: (E,d,f); rows >= group_sizes[e] give 0."""
+def moe_gmm_ref(x, w, group_sizes, layer=None):
+    """Grouped matmul: x: (E,C,d); w: (E,d,f), or (L,E,d,f) stacked with
+    ``layer`` the one read; rows >= group_sizes[e] give 0."""
+    if layer is not None:
+        w = w[layer]
     E, C, d = x.shape
     out = jnp.einsum("ecd,edf->ecf", x.astype(jnp.float32),
                      w.astype(jnp.float32))

@@ -167,13 +167,11 @@ def cache_pspecs(cache_shape, dp: Tuple[str, ...], batch: int,
         b_ax = rank - tree.shape[::-1].index(batch) - 1 if batch in tree.shape \
             else None
         if name in ("k_pages", "v_pages"):
-            # paged pools (..., n_pages, ps, KV, dh): no batch dim — pages
-            # are shared storage — so only the head dims can carry TP
+            # paged pools (..., n_pages, ps, KV*dh): no batch dim — pages
+            # are shared storage — so only the folded head dim can carry
+            # TP (whole KV heads per shard where KV divides the tp degree)
             spec = [None] * rank
-            if tree.shape[-2] % model_size == 0:
-                spec[-2] = MODEL
-            else:
-                spec[-1] = MODEL
+            spec[-1] = MODEL
             return P(*spec)
         if name in ("k", "v"):
             # (..., B, S, KV, dh)

@@ -36,13 +36,16 @@ def router_topk(x, w_router, top_k: int):
 def moe_ffn(x, params, *, top_k: int, capacity_factor: float = 1.25,
             gated: bool = True, shard_experts: bool = False,
             router_fn=None, positions=None, layer=None, valid=None,
-            backend: str = "reference", interpret: bool = True):
+            backend: str = "reference", interpret: bool = True,
+            expert_layer=None):
     """x: (T, d). params: router (d,E), w_gate/w_up (E,d,de), w_down (E,de,d).
 
     ``backend="pallas"`` swaps the three batched einsums for the fused
     grouped-GEMM kernel (``kernels.moe_gmm``) with per-expert group sizes
     from the dispatch counts — tiles past a group's size are skipped on
-    real TPUs (compute proportional to routed load, not capacity).
+    real TPUs (compute proportional to routed load, not capacity).  With
+    ``expert_layer`` (pallas only) the expert weights are a whole stage's
+    stack (L,E,d,de) and the kernel reads index ``expert_layer`` of it.
 
     ``router_fn`` is the injectable routing hook (``repro.moe.hooks``):
     called as ``router_fn(logits, positions=(T,), layer=scalar,
@@ -109,19 +112,16 @@ def moe_ffn(x, params, *, top_k: int, capacity_factor: float = 1.25,
         # valid rows per expert buffer; rows >= size are zero either way
         # (silu(0)*0 == 0, gelu(0) == 0), the kernel just skips their tiles
         group_sizes = jnp.minimum(counts[:E], C)
+
+        def gmm(h, name):
+            return moe_gmm(h, params[name].astype(x.dtype), group_sizes,
+                           expert_layer, interpret=interpret)
         if gated:
-            g = jax.nn.silu(moe_gmm(
-                hidden_in, params["w_gate"].astype(x.dtype), group_sizes,
-                interpret=interpret))
-            u = moe_gmm(hidden_in, params["w_up"].astype(x.dtype),
-                        group_sizes, interpret=interpret)
-            h = g * u
+            h = jax.nn.silu(gmm(hidden_in, "w_gate")) \
+                * gmm(hidden_in, "w_up")
         else:
-            h = jax.nn.gelu(moe_gmm(
-                hidden_in, params["w_up"].astype(x.dtype), group_sizes,
-                interpret=interpret))
-        out_e = moe_gmm(h, params["w_down"].astype(x.dtype), group_sizes,
-                        interpret=interpret)
+            h = jax.nn.gelu(gmm(hidden_in, "w_up"))
+        out_e = gmm(h, "w_down")
     elif gated:
         g = jax.nn.silu(jnp.einsum("ecd,edf->ecf", hidden_in,
                                    params["w_gate"].astype(x.dtype)))

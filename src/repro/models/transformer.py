@@ -193,26 +193,30 @@ def _attention(p, x, cfg: ArchConfig, *, positions, lengths, window,
     if paged and not pallas:
         raise ValueError("paged KV cache requires the pallas kernel "
                          "backend (kernels='pallas' or 'auto')")
-    if mode == "decode" and paged:
-        # paged slot-KV: scatter the new token through the block table,
-        # then one fused paged-attention walk over this sequence's pages
-        kc, vc = cache["k_pages"], cache["v_pages"]
+    if paged:
+        # decode, chunked-prefill extend and spec verify on the stage's
+        # stacked pools (L, n_pages, ps, KV*dh): scatter the new tokens in
+        # place at [layer, page, offset] through the block table, then one
+        # paged-attention walk over each sequence's pages of that layer.
+        # Writes past the table's reach (a full or unscheduled decode
+        # slot, an extend's pad tail) go to the scratch page, which is
+        # never read; they must not clobber a real token.
+        kc, vc, li = cache["k_pages"], cache["v_pages"], cache["layer"]
         ps = kernels.page_size
         maxp = block_table.shape[1]
-        pos = jnp.maximum(lengths - 1, 0)
-        pidx = pos // ps
-        page = block_table[jnp.arange(B), jnp.minimum(pidx, maxp - 1)]
-        # a full/unscheduled slot's garbage write goes to the scratch page
-        # (the contiguous path's equivalent out-of-bounds scatter is
-        # silently dropped; pages must not clobber a real token)
-        page = jnp.where(pidx < maxp, page, kc.shape[0] - 1)
-        off = pos % ps
-        kc = kc.at[page, off].set(k[:, 0].astype(kc.dtype))
-        vc = vc.at[page, off].set(v[:, 0].astype(vc.dtype))
+        pidx = positions // ps
+        page = block_table[jnp.arange(B)[:, None],
+                           jnp.minimum(pidx, maxp - 1)]
+        page = jnp.where(pidx < maxp, page, kc.shape[1] - 1)
+        off = positions % ps
+        kc = kc.at[li, page, off].set(
+            k.reshape(B, S, KV * dh).astype(kc.dtype))
+        vc = vc.at[li, page, off].set(
+            v.reshape(B, S, KV * dh).astype(vc.dtype))
         from repro.kernels import paged_attention
-        out = paged_attention(q[:, 0], kc, vc, block_table, lengths,
-                              page_size=ps, window=window,
-                              interpret=kernels.interpret)[:, None]
+        out = paged_attention(q, kc, vc, block_table, lengths, li,
+                              page_size=ps, start=positions[:, 0],
+                              window=window, interpret=kernels.interpret)
         new_cache = {"k_pages": kc, "v_pages": vc}
     elif mode == "decode":
         kc, vc = cache["k"], cache["v"]
@@ -222,29 +226,6 @@ def _attention(p, x, cfg: ArchConfig, *, positions, lengths, window,
         vc = vc.at[bidx, idx].set(v[:, 0].astype(vc.dtype))
         out = decode_attention(q, kc, vc, lengths=lengths, window=window)
         new_cache = {"k": kc, "v": vc}
-    elif mode == "extend" and paged:
-        # chunked-prefill continuation / spec verify on shared page pools:
-        # zero KV copies — the pages are the storage, the table the view
-        kc, vc = cache["k_pages"], cache["v_pages"]
-        ps = kernels.page_size
-        maxp = block_table.shape[1]
-        start = positions[:, 0]
-        pos = start[:, None] + jnp.arange(S)[None, :]
-        pidx = pos // ps
-        page = block_table[jnp.arange(B)[:, None],
-                           jnp.minimum(pidx, maxp - 1)]
-        # pad tails past the table's reach go to the scratch page (the
-        # contiguous path clamps them onto position max_len-1, which is
-        # only ever read after being rewritten; scratch is never read)
-        page = jnp.where(pidx < maxp, page, kc.shape[0] - 1)
-        off = pos % ps
-        kc = kc.at[page, off].set(k.astype(kc.dtype))
-        vc = vc.at[page, off].set(v.astype(vc.dtype))
-        from repro.kernels import paged_attention
-        out = paged_attention(q, kc, vc, block_table, lengths,
-                              page_size=ps, start=start, window=window,
-                              interpret=kernels.interpret)
-        new_cache = {"k_pages": kc, "v_pages": vc}
     elif mode == "extend":
         # chunked/cached prefill: S new slots written after `positions[:,0]`
         # (pad tail masked out by `lengths`); attend to the whole cache
@@ -301,7 +282,7 @@ def _attn_mlp_block(p, x, cfg, *, positions, lengths, window, mode, cache,
 def _attn_moe_block(p, x, cfg, *, positions, lengths, window, mode, cache,
                     attn_impl, unroll=False, shard_experts=False,
                     layer_idx=None, routing_hook=None, row_valid=None,
-                    kernels=None, block_table=None):
+                    kernels=None, block_table=None, expert_layer=None):
     h, new_cache = _attention(
         p["attn"], rmsnorm(x, p["norm1"], cfg.norm_eps), cfg,
         positions=positions, lengths=lengths, window=window, mode=mode,
@@ -338,7 +319,7 @@ def _attn_moe_block(p, x, cfg, *, positions, lengths, window, mode, cache,
                      backend=kernels.backend if kernels is not None
                      else "reference",
                      interpret=kernels.interpret if kernels is not None
-                     else True)
+                     else True, expert_layer=expert_layer)
     x = x + y.reshape(B, S, d)
     return x, new_cache, aux
 
@@ -408,9 +389,12 @@ class Model:
     kernel_backend: str = "reference"
     pallas_interpret: bool = True
     # paged slot-KV layout: attention caches become shared page pools
-    # ("k_pages"/"v_pages", (L, n_pages, page_size, KV, dh)) indexed by a
-    # per-sequence block table (cache["block_table"], (B, maxp) int32).
-    # Requires kernel_backend="pallas" and an all-attention stage list.
+    # ("k_pages"/"v_pages", (L, n_pages, page_size, KV*dh): a token's KV
+    # heads side by side in the last dim) indexed by a per-sequence block
+    # table (cache["block_table"], (B, maxp) int32).  A stage's stacked
+    # pools travel whole through its layers; layer li writes and reads
+    # index li of them (see ``_run_paged``).  Requires
+    # kernel_backend="pallas" and an all-attention stage list.
     paged: bool = False
     page_size: int = 64
 
@@ -496,6 +480,16 @@ class Model:
         # tables on the model-wide MoE layer, not the stage-local one
         moe_off = sum(s.n_layers for s in cfg.stages[:idx]
                       if s.kind == ATTN_MOE)
+        # the grouped-matmul kernel reads a layer's experts out of the
+        # whole stage stack at a scalar-prefetched index, so the stack is
+        # closure-captured too: a layer's experts sliced out of it as scan
+        # xs would be a copy of every expert's weights per layer
+        experts = None
+        if kind == ATTN_MOE and kernels is not None \
+                and not self.shard_experts:
+            moe = dict(sp["moe"])
+            experts = {k: moe.pop(k) for k in ("w_gate", "w_up", "w_down")}
+            sp = {**sp, "moe": moe}
 
         def layer(x, li, p, kcache):
             if kind == ATTN_MLP:
@@ -507,6 +501,8 @@ class Model:
                     norm_fn=rmsnorm_ct16 if self.norm_ct16 else rmsnorm,
                     kernels=kernels, block_table=block_table)
             if kind == ATTN_MOE:
+                if experts is not None:
+                    p = {**p, "moe": {**p["moe"], **experts}}
                 return _attn_moe_block(
                     p, x, cfg, positions=positions, lengths=lengths,
                     window=None, mode=mode, cache=kcache,
@@ -514,7 +510,8 @@ class Model:
                     shard_experts=self.shard_experts,
                     layer_idx=moe_off + li,
                     routing_hook=self.routing_hook, row_valid=row_valid,
-                    kernels=kernels, block_table=block_table)
+                    kernels=kernels, block_table=block_table,
+                    expert_layer=None if experts is None else li)
             if kind == MAMBA2:
                 return _mamba_block(p, x, cfg, mode=mode, cache=kcache)
             if kind == ZAMBA_SUPER:
@@ -529,6 +526,9 @@ class Model:
         if self.remat and mode == "train":
             layer = jax.checkpoint(
                 layer, policy=jax.checkpoint_policies.nothing_saveable)
+
+        if cache is not None and "k_pages" in cache:
+            return self._run_paged(layer, L, sp, x, cache)
 
         if self.unroll:
             new_caches_l, auxes_l = [], []
@@ -555,6 +555,36 @@ class Model:
         xs = (lis, sp, cache)
         x, (new_caches, auxes) = jax.lax.scan(body, x, xs)
         return x, new_caches, auxes.sum()
+
+    def _run_paged(self, layer, L, sp, x, cache):
+        """The layer stack over a paged cache.  The stacked pools travel
+        whole through the layers, each layer writing its tokens at its
+        own index and its kernel reading that index of the stack, so no
+        layer's pool is ever sliced out of the stack or written back."""
+        pools = (cache["k_pages"], cache["v_pages"])
+
+        def step(x, li, p, pools):
+            x, nc, aux = layer(x, li, p, {"k_pages": pools[0],
+                                          "v_pages": pools[1], "layer": li})
+            return x, (nc["k_pages"], nc["v_pages"]), aux
+
+        if self.unroll:
+            auxes = []
+            for li in range(L):
+                p = jax.tree_util.tree_map(lambda a: a[li], sp)
+                x, pools, aux = step(x, jnp.int32(li), p, pools)
+                auxes.append(aux)
+            aux = sum(auxes)
+        else:
+            def body(carry, xs):
+                x, pools = carry
+                li, p = xs
+                x, pools, aux = step(x, li, p, pools)
+                return (x, pools), aux
+            (x, pools), auxes = jax.lax.scan(body, (x, pools),
+                                             (jnp.arange(L), sp))
+            aux = auxes.sum()
+        return x, {"k_pages": pools[0], "v_pages": pools[1]}, aux
 
     def _zamba_super(self, p, x, li, kcache, shared_attn, *, positions,
                      lengths, mode):
@@ -762,7 +792,7 @@ class Model:
         def kv(n):
             if self.paged:
                 _, n_pages = self.page_geometry(batch, max_len)
-                shape = (n, n_pages, self.page_size, KV, dh)
+                shape = (n, n_pages, self.page_size, KV * dh)
                 return {"k_pages": jnp.zeros(shape, dtype),
                         "v_pages": jnp.zeros(shape, dtype)}
             return {"k": jnp.zeros((n, batch, max_len, KV, dh), dtype),

@@ -128,8 +128,8 @@ def kernel_points(arch: str, backend: str, *,
             continue
         npg = -(-ctx // page_size)
         for nb in sorted({1, max(1, max_batch // 2), max_batch}):
-            kp = rand(nb * npg, page_size, KV, dh)
-            vp = rand(nb * npg, page_size, KV, dh)
+            kp = rand(1, nb * npg, page_size, KV * dh)
+            vp = rand(1, nb * npg, page_size, KV * dh)
             table = jnp.arange(nb * npg, dtype=jnp.int32).reshape(nb, npg)
             lengths = jnp.full((nb,), ctx, jnp.int32)
 
@@ -137,11 +137,11 @@ def kernel_points(arch: str, backend: str, *,
             def attn_decode(x, kp, vp, table, lengths):
                 q, _, _ = split_qkv(x)
                 if backend == "pallas":
-                    o = paged_attention(q, kp, vp, table, lengths,
+                    o = paged_attention(q, kp, vp, table, lengths, 0,
                                         page_size=page_size,
                                         interpret=interpret)
                 else:
-                    o = paged_attention_ref(q, kp, vp, table, lengths,
+                    o = paged_attention_ref(q, kp, vp, table, lengths, 0,
                                             page_size=page_size)
                 return o.reshape(-1, H * dh) @ wo
 

@@ -70,6 +70,31 @@ def _bucket(n: int, lo: int = 16) -> int:
 _TIER_RANK = {"device": 0, "host": 1, "ssd": 2}
 
 
+#: (paged pool, contiguous array) keys of a stage cache's K and V
+_PAGED_KV = (("k_pages", "k"), ("v_pages", "v"))
+
+
+# The stacked pools ``(L, n_pages, ps, KV*dh)`` are read and written one
+# token row per (layer, token): indexed so, the pool keeps its row-major
+# layout and a write lands in place.  Sliced over the layer dim instead,
+# the TPU compiler moves the layer dim minor and copies the whole pool
+# out of that layout (and back, for a write).
+
+def _write_tokens(pool, page, off, kv):
+    """Contiguous K or V ``(L, T, KV, dh)`` of T tokens into the pool at
+    pages ``page`` and offsets ``off`` (each ``(T,)``)."""
+    layers = jnp.arange(pool.shape[0])[:, None]
+    return pool.at[layers, page, off].set(kv.reshape(kv.shape[:2] + (-1,)))
+
+
+def _read_tokens(pool, page, off, dh: int):
+    """The pool's K or V of T tokens at ``page``, ``off`` (each ``(T,)``)
+    as the contiguous ``(L, T, KV, dh)``."""
+    layers = jnp.arange(pool.shape[0])[:, None]
+    rows = pool[layers, page, off]
+    return rows.reshape(rows.shape[:2] + (-1, dh))
+
+
 def _payload_to_host(payload: dict) -> dict:
     """Device -> host copy of a store entry (metadata keys pass through)."""
     return {k: v if k.startswith("_")
@@ -501,16 +526,15 @@ class ServingEngine:
                         if key in ("lengths", "block_table"):
                             continue
                         out[key] = {
-                            "k_pages": cache[key]["k_pages"]
-                            .at[:, page, off].set(cache1[key]["k"][:, 0]),
-                            "v_pages": cache[key]["v_pages"]
-                            .at[:, page, off].set(cache1[key]["v"][:, 0]),
-                        }
+                            name: _write_tokens(cache[key][name], page, off,
+                                                cache1[key][kv][:, 0])
+                            for name, kv in _PAGED_KV}
                     out["lengths"] = cache["lengths"].at[slot].set(n)
                     return out
+                # the slot is traced (its table row is read on the
+                # device), so one program per bucket serves every slot
                 fn = self._put_jit("write_prefill_paged", P, jax.jit(
-                    write_prefill_paged, donate_argnums=(0,),
-                    static_argnums=(2,)))
+                    write_prefill_paged, donate_argnums=(0,)))
             self.cache = fn(self.cache, cache1, slot, n)
             return
         fn = self._get_jit("write_prefill", P)
@@ -548,11 +572,10 @@ class ServingEngine:
             fn = self._get_jit("subcache_paged", None)
             if fn is None:
                 def subcache_paged(table, slot, length):
-                    return (table[slot: slot + 1],
+                    return (jax.lax.dynamic_slice_in_dim(table, slot, 1),
                             jnp.full((1,), length, jnp.int32))
                 fn = self._put_jit("subcache_paged", None,
-                                   jax.jit(subcache_paged,
-                                           static_argnums=(1,)))
+                                   jax.jit(subcache_paged))
             table, lengths = fn(self.cache["block_table"], slot, length)
             return {**self.cache, "block_table": table, "lengths": lengths}
         fn = self._get_jit("subcache", None)
@@ -612,23 +635,19 @@ class ServingEngine:
             # store entries and P/D handoffs interoperate across layouts
             fn = self._get_jit("export_paged", blen)
             if fn is None:
-                ps, maxp = self.page_size, self._maxp
-                npg = min(-(-blen // ps), maxp)
+                ps, dh = self.page_size, self.cfg.d_head
 
                 def export_paged(cache, slot):
-                    pages = cache["block_table"][slot, :npg]
+                    pos = jnp.arange(blen)
+                    page = cache["block_table"][slot, pos // ps]
+                    off = pos % ps
                     out = {}
                     for key in cache:
                         if key in ("lengths", "block_table"):
                             continue
-                        kp = cache[key]["k_pages"][:, pages]
-                        vp = cache[key]["v_pages"][:, pages]
-                        L = kp.shape[0]
                         out[key] = {
-                            "k": kp.reshape((L, npg * ps) + kp.shape[3:])
-                            [:, :blen],
-                            "v": vp.reshape((L, npg * ps) + vp.shape[3:])
-                            [:, :blen]}
+                            kv: _read_tokens(cache[key][name], page, off, dh)
+                            for name, kv in _PAGED_KV}
                     return out
                 fn = self._put_jit("export_paged", blen,
                                    jax.jit(export_paged,
@@ -691,11 +710,9 @@ class ServingEngine:
                         if key in ("lengths", "block_table"):
                             continue
                         out[key] = {
-                            "k_pages": cache[key]["k_pages"]
-                            .at[:, page, off].set(kv[key]["k"]),
-                            "v_pages": cache[key]["v_pages"]
-                            .at[:, page, off].set(kv[key]["v"]),
-                        }
+                            name: _write_tokens(cache[key][name], page, off,
+                                                kv[key][k])
+                            for name, k in _PAGED_KV}
                     out["lengths"] = cache["lengths"].at[slot].set(n)
                     return out
                 fn = self._put_jit("restore_paged", blen, jax.jit(

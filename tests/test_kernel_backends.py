@@ -23,6 +23,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.configs.base import simple_stages
 from repro.core import ClusterCfg, RouterCfg
 from repro.core.cluster import Cluster
 from repro.kernels import ops, ref
@@ -58,55 +59,83 @@ def test_flash_gqa_lengths_window():
 
 def test_paged_decode_ragged_page_boundaries():
     H, KV, dh, ps, maxp = 4, 2, 16, 16, 4
-    B = 4
+    B, L, layer = 4, 3, 2
     P = B * maxp + 1
     ks = jax.random.split(jax.random.PRNGKey(1), 4)
     q = jax.random.normal(ks[0], (B, H, dh), jnp.float32)
-    kp = jax.random.normal(ks[1], (P, ps, KV, dh), jnp.float32)
-    vp = jax.random.normal(ks[2], (P, ps, KV, dh), jnp.float32)
+    # stacked pools of L layers, each with values of its own
+    kp = jax.random.normal(ks[1], (L, P, ps, KV * dh), jnp.float32)
+    vp = jax.random.normal(ks[2], (L, P, ps, KV * dh), jnp.float32)
     # block-table indirection: pages deliberately permuted across slots
     table = jax.random.permutation(ks[3], B * maxp).reshape(B, maxp)
     table = table.astype(jnp.int32)
     # lengths straddle page boundaries: 1, exactly one page, one page + 1,
     # and the full table
     lengths = jnp.array([1, ps, ps + 1, maxp * ps], jnp.int32)
-    out = ops.paged_attention(q, kp, vp, table, lengths, page_size=ps)
-    want = ref.paged_attention_ref(q, kp, vp, table, lengths, page_size=ps)
+    out = ops.paged_attention(q, kp, vp, table, lengths, layer,
+                              page_size=ps)
+    want = ref.paged_attention_ref(q, kp, vp, table, lengths, layer,
+                                   page_size=ps)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
 
 def test_paged_extend_crossing_pages():
     H, KV, dh, ps, maxp, S = 4, 2, 16, 8, 6, 12
-    B = 3
+    B, L, layer = 3, 2, 1
     P = B * maxp + 1
     ks = jax.random.split(jax.random.PRNGKey(2), 4)
     q = jax.random.normal(ks[0], (B, S, H, dh), jnp.float32)
-    kp = jax.random.normal(ks[1], (P, ps, KV, dh), jnp.float32)
-    vp = jax.random.normal(ks[2], (P, ps, KV, dh), jnp.float32)
+    kp = jax.random.normal(ks[1], (L, P, ps, KV * dh), jnp.float32)
+    vp = jax.random.normal(ks[2], (L, P, ps, KV * dh), jnp.float32)
     table = jax.random.permutation(ks[3], B * maxp).reshape(B, maxp)
     table = table.astype(jnp.int32)
     # chunks starting mid-page, on a boundary, and at zero
     start = jnp.array([ps - 3, ps, 0], jnp.int32)
     lengths = start + S
     for window in (None, 7):
-        out = ops.paged_attention(q, kp, vp, table, lengths, page_size=ps,
-                                  start=start, window=window)
-        want = ref.paged_attention_ref(q, kp, vp, table, lengths,
+        out = ops.paged_attention(q, kp, vp, table, lengths, layer,
+                                  page_size=ps, start=start, window=window)
+        want = ref.paged_attention_ref(q, kp, vp, table, lengths, layer,
                                        page_size=ps, start=start,
                                        window=window)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
 
 
-def test_moe_gmm_zero_and_uneven_groups():
+def test_paged_extend_heads_share_a_lane_tile():
+    """dh = 64: two KV heads per 128-lane tile, each read through the
+    whole tile with its query zero outside its own lanes (Granite's
+    widths: 3 query heads per KV head)."""
+    H, KV, dh, ps, maxp, S = 12, 4, 64, 16, 3, 9
+    B, L, layer = 2, 3, 2
+    P = B * maxp + 1
+    ks = jax.random.split(jax.random.PRNGKey(4), 4)
+    q = jax.random.normal(ks[0], (B, S, H, dh), jnp.float32)
+    kp = jax.random.normal(ks[1], (L, P, ps, KV * dh), jnp.float32)
+    vp = jax.random.normal(ks[2], (L, P, ps, KV * dh), jnp.float32)
+    table = jax.random.permutation(ks[3], B * maxp).reshape(B, maxp)
+    table = table.astype(jnp.int32)
+    start = jnp.array([ps - 2, 5], jnp.int32)
+    lengths = start + S
+    out = ops.paged_attention(q, kp, vp, table, lengths, layer,
+                              page_size=ps, start=start)
+    want = ref.paged_attention_ref(q, kp, vp, table, lengths, layer,
+                                   page_size=ps, start=start)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("layer", [None, 2], ids=["one", "stacked"])
+def test_moe_gmm_zero_and_uneven_groups(layer):
     E, C, d, f = 4, 48, 32, 24
     ks = jax.random.split(jax.random.PRNGKey(3), 2)
     x = jax.random.normal(ks[0], (E, C, d), jnp.float32)
-    w = jax.random.normal(ks[1], (E, d, f), jnp.float32)
+    shape = (E, d, f) if layer is None else (3, E, d, f)
+    w = jax.random.normal(ks[1], shape, jnp.float32)
     gs = jnp.array([C, 0, 5, 17], jnp.int32)   # full, empty, tiny, partial
-    out = ops.moe_gmm(x, w, gs, bc=16)
-    want = ref.moe_gmm_ref(x, w, gs)
+    out = ops.moe_gmm(x, w, gs, layer, bc=16)
+    want = ref.moe_gmm_ref(x, w, gs, layer)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
     assert not np.asarray(out)[1].any()        # zero-size group emits zeros
@@ -193,3 +222,87 @@ def test_paged_chunk_extend_reuses_the_page_pools():
     eng._write_slot(1, new, 48)
     assert all(eng.cache[k]["k_pages"] is new[k]["k_pages"] for k in stages)
     assert eng.cache["lengths"].tolist() == [0, 48]
+
+
+def _prefilled_pair(prompts):
+    """A paged (pallas) and a contiguous (reference) engine on the same
+    three-layer f32 weights, each slot prefilled with the same prompt of
+    ``prompts[slot]`` tokens and given room for one more."""
+    base = get_config(ARCH)
+    cfg = dataclasses.replace(
+        base, compute_dtype="float32", n_layers=3,
+        stages=simple_stages(base.stages[0].kind, 3))
+    paged = ServingEngine(dataclasses.replace(cfg, kernels="auto"),
+                          max_batch=len(prompts), max_len=256)
+    contig = ServingEngine(dataclasses.replace(cfg, kernels="reference"),
+                           params=paged.params, max_batch=len(prompts),
+                           max_len=256)
+    rng = np.random.default_rng(0)
+    tokens = {slot: jnp.asarray(rng.integers(1, cfg.vocab, (1, n)),
+                                jnp.int32) for slot, n in prompts.items()}
+    for eng in (paged, contig):
+        for slot, n in prompts.items():
+            _, c1 = eng._jit_prefill(eng.params, tokens[slot],
+                                     lengths=jnp.asarray([n], jnp.int32))
+            eng._write_slot_from_prefill(slot, c1, n)
+            eng.ensure_capacity(slot, n + 1)
+            eng._tokens_buf[slot, 0] = 7 + slot
+    return cfg, paged, contig
+
+
+def test_paged_decode_writes_only_the_new_tokens():
+    """One paged decode step changes the stacked pools exactly at
+    ``(layer, page, offset)`` of each row's new token, in every layer, and
+    writes there the K/V the contiguous reference engine stores for the
+    same token; other pages, offsets and the scratch page are unchanged."""
+    # slot 0's new token opens its second page; slot 1's lands mid-page
+    prompts = {0: 64, 1: 37}
+    cfg, paged, contig = _prefilled_pair(prompts)
+    before = jax.tree_util.tree_map(np.asarray, paged.cache)
+    for eng in (paged, contig):
+        _, eng.cache = eng._jit_decode(eng.params, eng.cache,
+                                       jnp.asarray(eng._tokens_buf))
+    after = jax.tree_util.tree_map(np.asarray, paged.cache)
+    table = after["block_table"]
+    assert table[0, 1] != paged._scratch
+    KV, dh = cfg.n_kv_heads, cfg.d_head
+    stages = [k for k in after if k.startswith("stage")]
+    for key in stages:
+        for pool, kv in (("k_pages", "k"), ("v_pages", "v")):
+            old, new = before[key][pool], after[key][pool]
+            L, n_pages, ps, F = new.shape
+            assert L == 3 and F == KV * dh
+            want = np.zeros((L, n_pages, ps), bool)
+            for slot, n in prompts.items():
+                want[:, table[slot, n // ps], n % ps] = True
+            np.testing.assert_array_equal((old != new).any(-1), want)
+            ref_kv = np.asarray(contig.cache[key][kv])
+            for slot, n in prompts.items():
+                got = new[:, table[slot, n // ps], n % ps]
+                np.testing.assert_allclose(
+                    got.reshape(L, KV, dh), ref_kv[:, slot, n],
+                    rtol=1e-5, atol=1e-5)
+
+
+def test_paged_export_restore_round_trip():
+    """A paged slot's export is the contiguous payload the reference
+    engine exports for the same tokens, and restoring it into another
+    slot reproduces it."""
+    prompts = {0: 70, 1: 5}
+    _, paged, contig = _prefilled_pair(prompts)
+    n = prompts[0]
+    got = paged._export_slot(0, n)
+    want = contig._export_slot(0, n)
+    stages = [k for k in paged.cache if k.startswith("stage")]
+    for key in stages:
+        for kv in ("k", "v"):
+            np.testing.assert_allclose(got[key][kv][:, :n],
+                                       want[key][kv][:, :n],
+                                       rtol=1e-5, atol=1e-5)
+    paged._release_slot(1)
+    paged._restore_slot(1, got, n)
+    again = paged._export_slot(1, n)
+    for key in stages:
+        for kv in ("k", "v"):
+            np.testing.assert_array_equal(again[key][kv][:, :n],
+                                          got[key][kv][:, :n])
