@@ -1,6 +1,6 @@
-"""Model FLOPs of the scheduled decode rows over the device time of the
-decode programs at the chip's bf16 peak."""
-from bench import costs
+"""Model FLOPs of the scheduled decode rows, as the cell's model family
+counts them, over the device time of the decode programs at the chip's
+bf16 peak."""
 from bench.xplane import decode_module
 
 
@@ -10,7 +10,7 @@ def read(ctx):
         m = decode_module(it)
         if m is None:
             continue
-        flops += costs.decode_step_flops(ctx.dims, it.decode)
+        flops += ctx.family.decode_step_flops(ctx.dims, it.decode)
         secs += m.dur * 1e-9
     if secs <= 0:
         return None

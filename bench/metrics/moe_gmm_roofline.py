@@ -7,10 +7,13 @@ expert), so the kernel reads every expert's weights; a short prompt routes
 too few rows for that, the kernel skips the experts left empty, and
 counting their bytes would put the share above what the chip can do."""
 from bench import costs
+from bench.families.transformer import Dims
 from bench.xplane import decode_module, kernel_calls
 
 
 def read(ctx):
+    if not isinstance(ctx.dims, Dims):
+        return None, f"no count of this kernel for {ctx.family.__name__}"
     if not ctx.dims.moe:
         return None
     least = secs = 0.0

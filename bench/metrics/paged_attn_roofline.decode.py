@@ -2,10 +2,13 @@
 the chip's peaks for the scheduled rows (their pages read once, their
 FLOPs), over the kernel's device time.  Memory bound at serving contexts."""
 from bench import costs
+from bench.families.transformer import Dims
 from bench.xplane import decode_module, kernel_calls
 
 
 def read(ctx):
+    if not isinstance(ctx.dims, Dims):
+        return None, f"no count of this kernel for {ctx.family.__name__}"
     least = secs = 0.0
     bounds = set()
     for it in ctx.iterations:

@@ -2,10 +2,13 @@
 and the paged kernel's calls in extend programs.  Least time at the chip's
 peaks for the real tokens over the kernels' device time."""
 from bench import costs
+from bench.families.transformer import Dims
 from bench.xplane import kernel_calls, paired_chunks
 
 
 def read(ctx):
+    if not isinstance(ctx.dims, Dims):
+        return None, f"no count of this kernel for {ctx.family.__name__}"
     least = secs = 0.0
     bounds = set()
     for it in ctx.iterations:

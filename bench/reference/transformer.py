@@ -16,7 +16,9 @@ no batching tricks, and nothing imported from the program under test.
   transpose (``tie_word_embeddings``).
 
 The model is run layer by layer, each layer's weights made afresh from the
-seed (``bench.weights``), so only one layer is ever held in float32.
+seed (``bench.families.transformer.layer_f32``), so only one layer is ever
+held in float32.  A reference of another family built from these
+equations reuses ``block``, ``embed``, ``final`` and ``readout``.
 ``quantize="fp8"`` is the control: the same forward computed in fp8, as
 an fp8 serving path would compute it: every weight matrix rounded to
 float8_e4m3 with one scale per output column, and every input of a
@@ -31,8 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import weights
-from bench.dims import Dims
+from bench.families.transformer import Dims, layer_f32, top_f32
 
 HI = jax.lax.Precision.HIGHEST
 
@@ -79,7 +80,7 @@ def _act(x, quantize):
     return x if quantize is None else _fp8_rows(x)
 
 
-def _maybe_quantize(tree, quantize):
+def maybe_quantize(tree, quantize):
     if quantize is None:
         return tree
     if quantize != "fp8":
@@ -88,8 +89,10 @@ def _maybe_quantize(tree, quantize):
 
 
 @functools.partial(jax.jit, static_argnums=(0, 4))
-def _block(dims: Dims, w, x, lengths, quantize):
-    w = _maybe_quantize(w, quantize)
+def block(dims: Dims, w, x, lengths, quantize):
+    """One decoder layer over ``x`` (S, L, d); ``quantize`` None or
+    ``"fp8"``, the control's precision."""
+    w = maybe_quantize(w, quantize)
     S, L, _ = x.shape
     H, KV, dh = dims.heads, dims.kv_heads, dims.d_head
     a = w["attn"]
@@ -132,12 +135,12 @@ def _block(dims: Dims, w, x, lengths, quantize):
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
-def _embed(dims: Dims, table, tokens):
+def embed(dims: Dims, table, tokens):
     return table[tokens] * dims.embedding_multiplier
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
-def _final(dims: Dims, gain, x):
+def final(dims: Dims, gain, x):
     return _rms(x, gain, dims.eps)
 
 
@@ -155,11 +158,10 @@ def _head_rows(dims: Dims, head, head_c, xr, xc, target):
 
 
 def _forward(dims: Dims, seed: int, tokens, lengths, top, quantize):
-    x = _embed(dims, _maybe_quantize(top["embed"]["tok"], quantize), tokens)
+    x = embed(dims, maybe_quantize(top["embed"]["tok"], quantize), tokens)
     for layer in range(dims.n_layers):
-        x = _block(dims, weights.layer_f32(dims, seed, layer), x, lengths,
-                   quantize)
-    return _final(dims, top["final_norm"], x)
+        x = block(dims, layer_f32(dims, seed, layer), x, lengths, quantize)
+    return final(dims, top["final_norm"], x)
 
 
 def logit_gaps(dims: Dims, seed: int, tokens: np.ndarray,
@@ -169,11 +171,19 @@ def logit_gaps(dims: Dims, seed: int, tokens: np.ndarray,
     whose ``targets`` entry is >= 0, the gap between the reference's best
     logit and that of the target token.  With ``control`` also the gap of
     the token the fp8 control puts first at the same positions."""
-    top = weights.top_f32(dims, seed)
+    top = top_f32(dims, seed)
     tok = jnp.asarray(tokens, jnp.int32)
     ln = jnp.asarray(lengths, jnp.int32)
     xr = _forward(dims, seed, tok, ln, top, None)
     xc = _forward(dims, seed, tok, ln, top, "fp8") if control else xr
+    return readout(dims, top, xr, xc, targets, control, rows)
+
+
+def readout(dims: Dims, top: dict, xr, xc, targets: np.ndarray,
+            control: bool, rows: int = 256) -> dict:
+    """The gaps of ``logit_gaps`` from the final hidden states (S, L, d) of
+    the reference (``xr``) and of the control (``xc``) and the unfolded
+    head of ``top``; ``dims`` gives ``vocab`` and ``logits_scaling``."""
     sel = np.nonzero(np.asarray(targets) >= 0)
     xr, xc = xr[sel], xc[sel]
     tgt = jnp.asarray(np.asarray(targets)[sel], jnp.int32)

@@ -189,8 +189,9 @@ class Setup:
         self.cell = cell
         self.counts = setup_jax()
         self.device = device_info(cell.chips, require_tpu)
-        self.cfg = cells.arch_config(cell, tiny=tiny)
-        self.dims = cells.tiny_dims(cell, self.cfg) if tiny else cell.dims
+        self.cfg = cell.family.arch_config(cell, tiny=tiny)
+        self.dims = cell.family.tiny_dims(cell, self.cfg) if tiny \
+            else cell.dims
         t0 = time.perf_counter()
         self.eng = ServingEngine(self.cfg, params=self.make_params(seed),
                                  max_batch=cell.max_batch,
@@ -206,9 +207,8 @@ class Setup:
 
     def make_params(self, seed: int):
         import jax
-        from bench import weights
-        return jax.block_until_ready(
-            weights.served_params(self.dims, seed, self.cfg.param_dtype))
+        return jax.block_until_ready(self.cell.family.served_params(
+            self.dims, seed, self.cfg.param_dtype))
 
     def load_params(self, seed: int):
         self.eng.params = None
@@ -404,8 +404,8 @@ def per_layer(cell, dims, device, s: Served):
     peaks = peaks_for(device["kind"])
     before = [r for r in s.requests if r.arrival < s.t_trace]
     ctx = xplane.Context(tr, lo, hi,
-                         xplane.iterations(tr, s.records, lo, hi), dims,
-                         peaks, cell.max_batch,
+                         xplane.iterations(tr, s.records, lo, hi),
+                         cell.family, dims, peaks, cell.max_batch,
                          stats.ttft_ms(before, s.stamps, s.t_open, s.t_close))
     log(f"trace: {device['window_s']!r} s, {len(ctx.iterations)} iterations, "
         f"device busy {device['busy_s']!r} s")

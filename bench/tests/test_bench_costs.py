@@ -2,7 +2,9 @@
 import pytest
 
 from bench import costs
-from bench.dims import Dims, dims_of, load_config
+from bench.cell import load_config
+from bench.families import transformer
+from bench.families.transformer import Dims
 
 # a small GQA shape whose counts are easy by hand
 D = Dims(n_layers=2, d=8, heads=4, kv_heads=2, d_head=2, d_ff=16, vocab=10,
@@ -48,9 +50,9 @@ def test_layer_params_dense_and_moe():
 
 def test_step_flops():
     per_row = 2 * (2 * D.layer_matmul_params() + 8 * 10)
-    assert costs.decode_step_flops(D, [5, 9]) == \
+    assert transformer.decode_step_flops(D, [5, 9]) == \
         2 * per_row + 2 * (5 + 9) * 32
-    assert costs.prefill_chunk_flops(D, 16, 4) == \
+    assert transformer.prefill_chunk_flops(D, 16, 4) == \
         2 * 2 * D.layer_matmul_params() * 4 + 2 * 8 * 10 \
         + 2 * costs.attn_flops(D, 16, 4)
 
@@ -64,7 +66,7 @@ def test_least_time_names_its_bound():
 @pytest.mark.parametrize("name,params", [("qwen3-8b-l18", 4.72e9),
                                          ("granite-moe-3b-a800m", 3.37e9)])
 def test_config_sizes(name, params):
-    d = dims_of(load_config(name))
+    d = transformer.dims(load_config(name))
     V, L = d.padded_vocab, d.n_layers
     norms = (2 * d.d + (2 * d.d_head if d.qk_norm else 0)) * L + d.d
     total = 2 * V * d.d + norms + L * (d.layer_matmul_params()

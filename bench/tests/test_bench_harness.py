@@ -10,6 +10,7 @@ import sys
 from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 from bench import cell as cells
@@ -97,10 +98,14 @@ def altered_token(setup, mp):
 
 
 def unchanged_state(setup, mp):
+    """Decode returns its input cache unchanged: a copy taken before the
+    call, which a decode that donates its cache leaves alive."""
     decode = setup.eng._jit_decode
-    mp.setattr(setup.eng, "_jit_decode",
-               lambda params, cache, tokens: (decode(params, cache,
-                                                     tokens)[0], cache))
+
+    def broken(params, cache, tokens):
+        kept = jax.tree_util.tree_map(jnp.copy, cache)
+        return decode(params, cache, tokens)[0], kept
+    mp.setattr(setup.eng, "_jit_decode", broken)
 
 
 def half_batch(setup, mp):
@@ -164,7 +169,12 @@ def test_cells_are_data():
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     for w in bench["workloads"]:
         c = cells.load(w["name"])
-        cfg = cells.arch_config(c)
+        family = c.conf.get("family", "transformer")
+        assert (ROOT / "bench" / "families" / f"{family}.py").exists()
+        assert c.family.__name__ == f"bench.families.{family}"
+        assert (ROOT / "bench" / "reference"
+                / f"{c.conf['reference']}.py").exists()
+        cfg = c.family.arch_config(c)
         assert cfg.kernels == "pallas" and cfg.param_dtype == "bfloat16"
         assert cfg.n_layers == c.dims.n_layers
     for m in bench["per_layer"]:

@@ -22,7 +22,7 @@ def reader(name):
 def ctx_of(host, modules=(), lo=100, hi=400):
     tr = Trace([Device("/device:TPU:0", list(modules))],
                [Span("traced", lo, hi), *host])
-    return xplane.Context(tr, lo, hi, [], None, {}, max_batch=4)
+    return xplane.Context(tr, lo, hi, [], None, None, {}, max_batch=4)
 
 
 def one_iteration():

@@ -36,7 +36,8 @@ def serve_once(eng, sched, reqs, annotate=False):
 def traced(tmp_path_factory):
     from repro.core.config import SchedulerCfg
     from repro.serve.engine import ServingEngine
-    cfg = cells.arch_config(cells.load("qwen3-8b-l18.chat"), tiny=True)
+    cell = cells.load("qwen3-8b-l18.chat")
+    cfg = cell.family.arch_config(cell, tiny=True)
     eng = ServingEngine(cfg, max_batch=2, max_len=128, name="e0")
     assert eng.paged
     sched = SchedulerCfg(max_batch_size=2, max_batch_tokens=32,

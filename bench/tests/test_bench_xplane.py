@@ -6,7 +6,8 @@ from pathlib import Path
 import pytest
 
 from bench import xplane
-from bench.dims import Dims
+from bench.families import transformer
+from bench.families.transformer import Dims
 from bench.xplane import Module, Span, Trace, Device
 
 METRICS = Path(__file__).resolve().parents[1] / "metrics"
@@ -103,7 +104,8 @@ def synthetic():
 def context():
     tr, records = synthetic()
     its = xplane.iterations(tr, records, 100, 400)
-    return xplane.Context(tr, 100, 400, its, D, PEAKS, max_batch=4)
+    return xplane.Context(tr, 100, 400, its, transformer, D, PEAKS,
+                          max_batch=4)
 
 
 def test_iterations_get_their_programs():
@@ -140,10 +142,10 @@ def test_mfu_and_rooflines_from_the_counts():
     from bench import costs
     ctx = context()
     assert reader("decode_mfu")(ctx) == pytest.approx(
-        100 * costs.decode_step_flops(D, [64, 65]) / (40e-9 * 1e9))
+        100 * transformer.decode_step_flops(D, [64, 65]) / (40e-9 * 1e9))
     assert reader("prefill_mfu")(ctx) == pytest.approx(
-        100 * (costs.prefill_chunk_flops(D, 0, 16)
-               + costs.prefill_chunk_flops(D, 32, 8)) / (60e-9 * 1e9))
+        100 * (transformer.prefill_chunk_flops(D, 0, 16)
+               + transformer.prefill_chunk_flops(D, 32, 8)) / (60e-9 * 1e9))
     t, bound = costs.least_time(*costs.paged_decode(D, [64, 65]), PEAKS)
     value, note = reader("paged_attn_roofline.decode")(ctx)
     assert value == pytest.approx(100 * 2 * t / 11e-9) and bound in note
@@ -169,7 +171,8 @@ def test_moe_gmm_roofline_reads_decode_calls_only():
                4: {"decode": [], "chunks": [(0, 16)]}}
     tr = Trace([Device("/device:TPU:0", [dec, pre])], host)
     its = xplane.iterations(tr, records, 100, 400)
-    ctx = xplane.Context(tr, 100, 400, its, moe, PEAKS, max_batch=4)
+    ctx = xplane.Context(tr, 100, 400, its, transformer, moe, PEAKS,
+                         max_batch=4)
     t, bound = costs.least_time(*costs.moe_gmm(moe, 2), PEAKS)
     value, note = reader("moe_gmm_roofline")(ctx)
     assert value == pytest.approx(100 * 2 * t / 13e-9) and bound in note
@@ -177,7 +180,8 @@ def test_moe_gmm_roofline_reads_decode_calls_only():
 
 def test_readers_find_nothing_in_an_empty_window():
     tr, _ = synthetic()
-    ctx = xplane.Context(tr, 500, 600, [], D, PEAKS, max_batch=4)
+    ctx = xplane.Context(tr, 500, 600, [], transformer, D, PEAKS,
+                         max_batch=4)
     for name in ("decode_mfu", "prefill_mfu", "paged_attn_roofline.decode",
                  "prefill_attn_roofline", "batch_occupancy",
                  "ttft_p90_ms.before_trace"):
@@ -186,7 +190,8 @@ def test_readers_find_nothing_in_an_empty_window():
 
 def test_ttft_tail_before_the_trace():
     tr, _ = synthetic()
-    ctx = xplane.Context(tr, 100, 400, [], D, PEAKS, max_batch=4,
+    ctx = xplane.Context(tr, 100, 400, [], transformer, D, PEAKS,
+                         max_batch=4,
                          ttft_ms=[float(v) for v in range(10, 110, 10)])
     assert reader("ttft_p90_ms.before_trace")(ctx) == pytest.approx(91.0)
 

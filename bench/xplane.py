@@ -219,13 +219,15 @@ def breakdown(trace: Trace, lo: float, hi: float) -> dict:
 @dataclasses.dataclass
 class Context:
     """What a per-layer metric reader gets: the reduced trace, the traced
-    window [lo, hi] in trace ns, the iterations inside it, the cell, and
-    the wall-clock readings of the window before the trace."""
+    window [lo, hi] in trace ns, the iterations inside it, the cell's
+    model family and shapes, the chip's peaks, and the wall-clock readings
+    of the window before the trace."""
     trace: Trace
     lo: float
     hi: float
     iterations: List[Iteration]
-    dims: object              # bench.dims.Dims
+    family: object            # the configuration's bench/families module
+    dims: object              # family.dims of the served model
     peaks: dict
     max_batch: int
     #: time to first token (ms) of each request due in the window before
