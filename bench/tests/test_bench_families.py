@@ -8,7 +8,10 @@ configuration's ``"family"`` and ``"reference"`` keys as files under
 the CPU it is served through ``run.Setup`` and a paced run on the Pallas
 paged path as two program stages (``ATTN_MLP``, then ``ATTN_MOE``), read
 correct, and read not correct under each planted fault and the fp8
-control.  Nothing of the harness is edited for it."""
+control.  Nothing of the harness is edited for it.  That the benchmark's own
+tests also take a new configuration, cell and metric unedited, added to
+``BENCHMARK.json`` as new files plus entries, is shown by
+``test_bench_additions.py``."""
 import copy
 import dataclasses
 import functools
@@ -222,7 +225,7 @@ def two_stage_modules():
 def two_stage_cell(conf=CONF):
     chat = H.tiny_cell("qwen3-8b-l18.chat")
     spec = dict(copy.deepcopy(chat.spec),
-                check={"requests": H.TINY_SAMPLE, "limits": LIMITS})
+                check=dict(chat.spec["check"], limits=LIMITS))
     return cells.Cell("two-stage-test.chat", 1, conf, chat.traffic, spec)
 
 
@@ -272,7 +275,8 @@ def test_paced_run_is_correct(setup):
     assert setup.eng.paged and len(setup.cfg.stages) == 2
     s, res = H.paced(setup, seed=SEED)
     assert s.lowered == 0 and s.active and s.failed == 0
-    assert res["requests"] == min(H.TINY_SAMPLE, len(s.finished)) >= 4
+    sample = setup.cell.spec["check"]["requests"]
+    assert res["requests"] == min(sample, len(s.finished)) >= 4
     assert res["missing_tokens"] == 0
     assert run.judge(setup.cell, res)[0]
 

@@ -19,21 +19,15 @@ from bench import run
 ROOT = Path(__file__).resolve().parents[2]
 SEED = 2 ** 31 + 101
 SECONDS = 2.0
-CELLS = ["qwen3-8b-l18.chat", "granite-moe-3b-a800m.offline"]
-#: limits of the check at the ``-tiny`` sizes, set as the cells' are, from
-#: CPU readings over every request finished in a 4 s window, seeds 5..10 and
-#: SEED.  qwen3: program widest / mean gap up to 0.043 / 2.9e-4, the fp8
-#: control at least 0.666 / 0.0287.  granite, whose tied head over
-#: ``logits_scaling`` 6 makes logits of about 0.01: program mean up to
-#: 5.4e-6, control at least 2.5e-5; its widest gaps overlap (program up to
-#: 2.4e-3, control from 1.8e-3), as on the chip, so only the mean is
-#: compared.  A sample of twelve requests keeps the mean steady whichever
-#: requests a loaded CPU finishes in the window.
-TINY_LIMITS = {
-    "qwen3-8b-l18.chat": {"max_logit_gap": 0.15, "mean_logit_gap": 0.004},
-    "granite-moe-3b-a800m.offline": {"mean_logit_gap": 1.2e-5},
-}
-TINY_SAMPLE = 12
+BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
+#: every one-chip cell of the benchmark; each brings the limits of its check
+#: at the ``-tiny`` sizes, and why, under ``"tiny_check"`` in its cell file
+CELLS = [w["name"] for w in BENCH["workloads"] if w["chips"] == 1]
+#: the per-layer metrics that read every cell, whatever its family: a new
+#: cell's name joins each of their ``workloads`` lists
+EVERY_CELL = ["host_gap_share", "decode_mfu", "device_idle_share",
+              "device_idle_share.prepare", "device_idle_share.launch",
+              "device_idle_share.sample", "device_idle_share.runtime"]
 _CACHE_KEYS = ("jax_compilation_cache_dir",
                "jax_persistent_cache_min_compile_time_secs",
                "jax_persistent_cache_min_entry_size_bytes")
@@ -43,7 +37,8 @@ def tiny_cell(name: str):
     c = cells.load(name)
     c.spec, c.traffic = copy.deepcopy(c.spec), copy.deepcopy(c.traffic)
     c.spec["engine"] = {"max_batch": 4, "max_len": 256}
-    c.spec["check"] = {"requests": TINY_SAMPLE, "limits": TINY_LIMITS[name]}
+    tiny = c.spec["tiny_check"]
+    c.spec["check"] = {"requests": tiny["requests"], "limits": tiny["limits"]}
     c.traffic["prompt"]["max"] = 64
     c.traffic["output"].update(min=4, max=40)
     if "rate" in c.spec:
@@ -83,7 +78,8 @@ def test_paced_run_is_correct(setup):
     assert all(ts[0] > due[rid] for rid, ts in s.stamps.items())
     assert all(ts == sorted(ts) for ts in s.stamps.values())
     assert s.active and s.failed == 0
-    assert res["requests"] == min(TINY_SAMPLE, len(s.finished)) >= 4
+    sample = setup.cell.spec["check"]["requests"]
+    assert res["requests"] == min(sample, len(s.finished)) >= 4
     assert res["missing_tokens"] == 0
     assert run.judge(setup.cell, res)[0]
     e2e = run.end_to_end(s, SECONDS)
@@ -166,8 +162,7 @@ def test_needs_the_program_beside_it(tmp_path):
 
 
 def test_cells_are_data():
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    for w in bench["workloads"]:
+    for w in BENCH["workloads"]:
         c = cells.load(w["name"])
         family = c.conf.get("family", "transformer")
         assert (ROOT / "bench" / "families" / f"{family}.py").exists()
@@ -177,5 +172,24 @@ def test_cells_are_data():
         cfg = c.family.arch_config(c)
         assert cfg.kernels == "pallas" and cfg.param_dtype == "bfloat16"
         assert cfg.n_layers == c.dims.n_layers
-    for m in bench["per_layer"]:
+    for m in BENCH["per_layer"]:
         assert (ROOT / "bench" / "metrics" / f"{m['name']}.py").exists()
+
+
+@pytest.mark.parametrize("name", [w["name"] for w in BENCH["workloads"]])
+def test_every_cell_is_read_and_checked(name):
+    """Each metric that reads every cell lists this one, and a one-chip
+    cell brings the limits of its check at the ``-tiny`` sizes."""
+    listed = {m["name"]: m.get("workloads", [name])
+              for m in BENCH["per_layer"]}
+    assert [m for m in EVERY_CELL if name not in listed[m]] == []
+    if name in CELLS:
+        tiny = cells.load(name).spec["tiny_check"]
+        assert tiny["requests"] > 0 and tiny["limits"] and tiny["why"]
+
+
+@pytest.mark.parametrize("metric", BENCH["per_layer"],
+                         ids=lambda m: m["name"])
+def test_every_listed_cell_is_a_cell(metric):
+    assert set(metric.get("workloads", [])) <= \
+        {w["name"] for w in BENCH["workloads"]}

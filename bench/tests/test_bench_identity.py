@@ -1,7 +1,12 @@
 """The transformer family behind ``bench/families`` computes what the
 harness computed before it had families: served weights, the program's
 config, model FLOP counts and every per-layer reading on the recorded
-traces equal values recorded from that harness, exactly."""
+traces equal values recorded from that harness, exactly.
+
+The pins cover the cells and readers recorded here.  A later cell, metric
+or reader enters as new files plus entries and is pinned by tests of its
+own; ``test_bench_additions.py`` shows that such an addition leaves these
+tests passing."""
 import gzip
 import hashlib
 import importlib.util
@@ -127,8 +132,24 @@ READINGS = {
 IDLE_NOTES = {"qwen3-8b-l18.chat": (21.756480445035557, 22.936481898994003),
               "granite-moe-3b-a800m.offline": (43.90122827365862,
                                                45.69872318404018)}
-METRICS = [m["name"] for m in
-           json.loads((ROOT / "BENCHMARK.json").read_text())["per_layer"]]
+#: the metrics whose readers are pinned here
+METRICS = list(READINGS["qwen3-8b-l18.chat"])
+#: each of them with the cells it listed when its readings were recorded
+LISTED = {
+    "host_gap_share": CELLS,
+    "ttft_p90_ms.before_trace": ["qwen3-8b-l18.chat"],
+    "batch_occupancy": ["granite-moe-3b-a800m.offline"],
+    "decode_mfu": CELLS,
+    "prefill_mfu": ["qwen3-8b-l18.chat"],
+    "paged_attn_roofline.decode": CELLS,
+    "prefill_attn_roofline": ["qwen3-8b-l18.chat"],
+    "moe_gmm_roofline": ["granite-moe-3b-a800m.offline"],
+    "device_idle_share": CELLS,
+    "device_idle_share.prepare": CELLS,
+    "device_idle_share.launch": CELLS,
+    "device_idle_share.sample": CELLS,
+    "device_idle_share.runtime": CELLS,
+}
 
 
 def reader(name):
@@ -196,3 +217,17 @@ def test_reader_on_recorded_trace(contexts, name, metric):
     if metric.startswith("device_idle_share."):
         want = (want, NOTE.format(*IDLE_NOTES[name]))
     assert got == want
+
+
+def test_recorded_metrics_are_in_the_benchmark():
+    """Every metric pinned here is still a per-layer metric of the
+    benchmark and still lists the cells it listed when recorded."""
+    per_layer = {m["name"]: m for m in
+                 json.loads((ROOT / "BENCHMARK.json").read_text())
+                 ["per_layer"]}
+    assert all(list(READINGS[name]) == METRICS for name in CELLS)
+    assert list(LISTED) == METRICS
+    for metric, listed in LISTED.items():
+        assert metric in per_layer, metric
+        assert set(listed) <= set(per_layer[metric].get("workloads",
+                                                        listed)), metric
